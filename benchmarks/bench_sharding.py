@@ -1,6 +1,6 @@
 """Sharded-execution scaling: the 1→N device curve (beyond paper).
 
-Compiles each TPC-H query at ``Settings(shards=N)`` for N in {1, 2, 4, 8}
+Compiles each TPC-H query at ``Settings(shards=N)`` for N in {1, 2, 4, ...}
 and records, per query and mesh size:
 
   * best wall-clock per execution (same protocol as bench_ladder),
@@ -12,11 +12,11 @@ and records, per query and mesh size:
     (the verifier's `exchange-count` rule bounds the former by the
     non-co-partitioned consumers during optimize()).
 
-The mesh needs 8 visible devices and XLA fixes its device list at the
-first jax import, so when this process can't see 8 (the usual case —
-`benchmarks/run.py` imported jax long ago) the benchmark re-executes
-itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+Mesh sizes are the powers of two up to the number of devices this
+process sees (`jax.devices()`).  Everything runs in this one process: on
+a TPU host a child process could not reach the chips its parent holds.
+On a CPU, simulate devices by setting
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the run.
 
 Writes ``BENCH_sharding.json`` (or $REPRO_BENCH_SHARD_OUT).  Scale
 factor comes from $REPRO_SF like every other bench; the nightly scaling
@@ -27,14 +27,12 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 
-MESHES = (1, 2, 4, 8)
 QUICK_KEEP = {"q1", "q3", "q6", "q12"}
 
 
-def _run_local() -> None:
+def run() -> None:
     import jax
 
     from benchmarks.common import SF, csv, db, time_compiled
@@ -48,12 +46,11 @@ def _run_local() -> None:
     names = sorted(QUERIES)
     if os.environ.get("REPRO_QUICK") == "1":
         names = [q for q in names if q in QUICK_KEEP]
+    meshes = [1 << i for i in range(n_dev.bit_length())]
     out: dict = {"sf": SF, "devices": n_dev, "queries": {}}
     for qname in names:
         rows = []
-        for n in MESHES:
-            if n > n_dev:
-                continue
+        for n in meshes:
             settings = dataclasses.replace(preset("opt"), shards=n)
             lowered = optimize(QUERIES[qname](), d, settings)
             nodes = list(ir.walk(lowered))
@@ -89,34 +86,5 @@ def _run_local() -> None:
         json.dump(out, f, indent=2)
 
 
-def run() -> None:
-    import jax
-
-    if len(jax.devices()) >= max(MESHES):
-        _run_local()
-        return
-    # jax already pinned this process to fewer devices: rerun ourselves
-    # with the simulation flag set before any import can touch jax.
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={max(MESHES)}"
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sharding"],
-        env=env, capture_output=True, text=True)
-    sys.stdout.write(proc.stdout)
-    if proc.returncode != 0:
-        sys.stderr.write(proc.stderr)
-        raise RuntimeError(
-            f"sharding sweep subprocess failed ({proc.returncode})")
-
-
 if __name__ == "__main__":
-    if "jax" not in sys.modules:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count"
-                        f"={max(MESHES)}").strip()
-    _run_local()
+    run()

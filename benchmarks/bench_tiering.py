@@ -154,9 +154,7 @@ def _converge(cache, settings, build, initial, steady) -> dict:
 
 
 def _bench_warm_restart(database, settings, out, workdir) -> dict:
-    xla_cache = os.path.join(workdir, "xla-cache")
-    section = {"jax_compilation_cache_enabled":
-               enable_compilation_cache(xla_cache)}
+    section = {"jax_compilation_cache_dir": enable_compilation_cache()}
     for qname, init_overlay in WARM_SCHEDULES.items():
         build, defaults = PARAM_QUERIES[qname]
         initial = dict(defaults, **init_overlay)

@@ -4,8 +4,8 @@ from __future__ import annotations
 import os
 import time
 
-
 from repro.core import CompiledQuery, VolcanoEngine, preset
+from repro.core.persist import enable_compilation_cache
 from repro.relational import Database
 from repro.relational.queries import QUERIES
 
@@ -18,6 +18,7 @@ _DB = None
 def db() -> Database:
     global _DB
     if _DB is None:
+        enable_compilation_cache()
         _DB = Database.tpch(sf=SF)
     return _DB
 

@@ -6,7 +6,8 @@ ladder, and show the abstraction-without-regret effect.
 import argparse
 import time
 
-from repro.core import CompiledQuery, VolcanoEngine, preset
+from repro.core import (CompiledQuery, VolcanoEngine, enable_compilation_cache,
+                        preset)
 from repro.core.ir import plan_repr
 from repro.relational import Database
 from repro.relational.queries import q6, q12
@@ -16,6 +17,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--sf", type=float, default=0.02)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     print(f"Generating TPC-H (sf={args.sf}) ...")
     db = Database.tpch(sf=args.sf)

@@ -7,7 +7,7 @@ and report per-query timings, memory and compile cost.
 import argparse
 import time
 
-from repro.core import CompiledQuery, preset
+from repro.core import CompiledQuery, enable_compilation_cache, preset
 from repro.relational import Database
 from repro.relational.queries import QUERIES
 
@@ -19,6 +19,7 @@ def main():
                     choices=["naive", "template", "tpch", "strdict", "opt",
                              "opt-pallas"])
     args = ap.parse_args()
+    enable_compilation_cache()
 
     t0 = time.perf_counter()
     db = Database.tpch(sf=args.sf)

@@ -230,7 +230,7 @@ def random_plan(rng: np.random.Generator, db: Database) -> ir.Plan:
 
 
 # ---------------------------------------------------------------------------
-# oracle-equivalence checking (mirrors tests/test_queries.py's canon)
+# oracle-equivalence checking (mirrors volcano.canon / assert_same)
 # ---------------------------------------------------------------------------
 
 

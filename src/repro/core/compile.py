@@ -58,7 +58,7 @@ import numpy as np
 from repro.core import ir
 from repro.core.backend import JaxBackend, NumpyBackend
 from repro.core.expr import Param
-from repro.core.mesh import AXIS, data_mesh, resolve_shards, shard_map_fn
+from repro.core.mesh import AXIS, data_mesh, resolve_shards
 from repro.core.operators import StageCtx, frame_nrows
 from repro.core.passes.param_binding import plan_params
 from repro.core.passes.pipeline import Settings, optimize
@@ -281,7 +281,8 @@ class CompiledQuery:
             # inputs split along the data axis, everything else (params
             # included) replicated.  Every output is replicated — the plan
             # ends in combined aggregates or above a gather Exchange, and
-            # the counts are all-gathered in `body` — so out_specs is P().
+            # the counts are all-gathered in `body` — so out_specs is P(),
+            # a replication the checker cannot always infer (check_vma off).
             # The in_specs dict is built per call because `bind` adds
             # param/<name> keys the collection-time input set lacks.
             from jax.sharding import PartitionSpec
@@ -290,8 +291,10 @@ class CompiledQuery:
                 specs = {k: (PartitionSpec(AXIS) if k in self.sharded_keys
                              else PartitionSpec())
                          for k in inputs}
-                return shard_map_fn(inner, self._mesh, in_specs=(specs,),
-                                    out_specs=PartitionSpec())(inputs)
+                return jax.shard_map(inner, mesh=self._mesh,
+                                     in_specs=(specs,),
+                                     out_specs=PartitionSpec(),
+                                     check_vma=False)(inputs)
             return call
 
         self.fn = fn if self._mesh is None else shard_wrap(fn)

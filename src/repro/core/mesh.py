@@ -53,16 +53,3 @@ def data_mesh(n: int):
     _MESHES[n] = mesh
     return mesh
 
-
-def shard_map_fn(fn, mesh, in_specs, out_specs, check_rep=False):
-    """Version-tolerant shard_map wrapper (jax.shard_map moved out of
-    experimental after 0.4.x)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep)
-    except TypeError:  # newer jax dropped/renamed check_rep
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)

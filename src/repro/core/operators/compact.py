@@ -20,7 +20,7 @@ counts feed the plan cache's adaptive capacity feedback.
 
 Under `Settings.use_pallas` the XLA three-op sequence (cumsum →
 searchsorted → gather-rank) is replaced by the single-HBM-pass Pallas
-kernel (`repro.kernels.compact`), and when the child is a Select whose
+kernel (`repro.kernels.pipeline`), and when the child is a Select whose
 predicate is kernel-safe over an elementwise chain, predicate evaluation
 itself is fused into the same pass (`compact_pred`): the mask is never
 materialized in HBM.  `translate` points additionally emit the CSR

@@ -142,24 +142,30 @@ def load_warm_state(cache, path: str) -> int:
         return 0
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at `cache_dir` so the XLA
-    compile itself survives restarts: a re-staged program whose HLO
-    matches a cached executable deserializes instead of recompiling.
-    Thresholds are zeroed (every entry qualifies) and the XLA-level
-    caches are enabled where the backend supports them (required for the
-    CPU backend).  Returns False — changing nothing — on a JAX too old
-    for the config knobs; never raises."""
+def compilation_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives:
+    `$JAX_COMPILATION_CACHE_DIR` when set, else `.jax_cache/` at the root
+    of this checkout.  The path is part of every entry's identity, so it
+    is fixed: a directory that moves between runs never hits."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+        ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache so the XLA compile
+    survives restarts: a re-staged program whose HLO matches a cached
+    executable deserializes instead of recompiling.  JAX reads
+    `$JAX_COMPILATION_CACHE_DIR` itself; only without it is the directory
+    set here.  Thresholds are zeroed (every entry qualifies) and the
+    XLA-level caches are enabled.  Returns the directory."""
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:
-        return False
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except (AttributeError, ValueError):
-        pass   # older JAX: GPU/TPU caching still works without it
-    return True
+    cache_dir = os.path.normpath(compilation_cache_dir())
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    return cache_dir

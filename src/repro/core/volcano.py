@@ -383,3 +383,36 @@ def _scalar_agg(fn: str, v, n: int):
     if fn == "max":
         return v.max()
     raise ValueError(fn)
+
+
+def canon(res: dict[str, np.ndarray], sort: bool) -> dict[str, np.ndarray]:
+    """Canonicalize a result: columns by name, and with `sort` the rows
+    ordered by all columns (floats rounded to 2 places for the order)."""
+    names = sorted(res)
+    if not sort:
+        return {k: res[k] for k in names}
+    keys = [np.round(res[k].astype(np.float64), 2)
+            if res[k].dtype.kind == "f" else res[k] for k in names]
+    order = np.lexsort(tuple(reversed(keys)))
+    return {k: res[k][order] for k in names}
+
+
+def assert_same(a: dict, b: dict, sort_insensitive: bool) -> None:
+    """Raise AssertionError unless two results agree: the same columns
+    and row counts, exact integer and string columns, and float columns
+    within rtol=2e-3, atol=1e-2 (float32 money columns, summed in a
+    different order by each engine).  `sort_insensitive` compares the
+    rows as a set (`relational.queries.SORT_INSENSITIVE`)."""
+    if set(a) != set(b):
+        raise AssertionError(f"columns differ: {set(a)} vs {set(b)}")
+    ca, cb = canon(a, sort_insensitive), canon(b, sort_insensitive)
+    for k in ca:
+        va, vb = ca[k], cb[k]
+        if len(va) != len(vb):
+            raise AssertionError(f"{k}: {len(va)} vs {len(vb)} rows")
+        if va.dtype.kind == "f" or vb.dtype.kind == "f":
+            np.testing.assert_allclose(
+                va.astype(np.float64), vb.astype(np.float64),
+                rtol=2e-3, atol=1e-2, err_msg=k)
+        else:
+            np.testing.assert_array_equal(va, vb, err_msg=k)

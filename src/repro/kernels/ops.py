@@ -1,11 +1,11 @@
-"""Jitted public wrappers around the Pallas kernels.
+"""Query-engine entry points to the Pallas kernel (`kernels.pipeline`).
 
 `interpret` selects the Pallas execution mode: `None` (the default)
-auto-detects — compiled Mosaic/Triton kernels when a TPU or GPU backend is
-present, the (slow, validation-only) Pallas interpreter on CPU.  Pass an
-explicit bool to force either mode (`Settings.pallas_interpret` threads the
-engine-level override through).  `filter_agg_query` is the integration
-point used by `repro.core.operators.agg` when `Settings.use_pallas` is on;
+compiles the kernel when JAX's backend is a TPU and runs the (slow,
+validation-only) Pallas interpreter elsewhere; an explicit bool forces
+either mode (`Settings.pallas_interpret` threads the engine-level override
+through).  `filter_agg_query` is the integration point used by
+`repro.core.operators.agg` when `Settings.use_pallas` is on;
 `compact_query` / `compact_pred_query` / `selective_agg_query` are the
 corresponding single-pass entry points for `operators.compact` and the
 fused selective pipeline.
@@ -14,25 +14,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.compact import compact, compact_pred, compact_translate
-from repro.kernels.filter_agg import filter_agg, selective_filter_agg
-from repro.kernels.gather_join import gather_join
-from repro.kernels.topk import masked_topk
+from repro.kernels.pipeline import (compact, compact_pred, compact_translate,
+                                    filter_agg, resolve_interpret,
+                                    selective_filter_agg)
 
-__all__ = ["filter_agg", "gather_join", "masked_topk", "filter_agg_query",
-           "compact", "compact_translate", "compact_pred", "compact_query",
-           "compact_pred_query", "selective_filter_agg",
-           "selective_agg_query", "resolve_interpret"]
-
-
-def resolve_interpret(interpret: "bool | None") -> bool:
-    """Resolve an interpret override: None = interpret only when no
-    accelerator backend (TPU/GPU) is available to compile the kernels."""
-    if interpret is not None:
-        return bool(interpret)
-    import jax
-
-    return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
+__all__ = ["filter_agg", "filter_agg_query", "compact", "compact_translate",
+           "compact_pred", "compact_query", "compact_pred_query",
+           "selective_filter_agg", "selective_agg_query",
+           "resolve_interpret"]
 
 
 def filter_agg_query(mask, gidx, value_cols, n_groups, *, interpret=None):
@@ -41,7 +30,7 @@ def filter_agg_query(mask, gidx, value_cols, n_groups, *, interpret=None):
     ones = jnp.ones_like(mask, dtype=jnp.float32)
     vals = jnp.stack(list(value_cols) + [ones], axis=1).astype(jnp.float32)
     out = filter_agg(mask, gidx.astype(jnp.int32), vals, n_groups,
-                     interpret=resolve_interpret(interpret))
+                     interpret=interpret)
     return out[:, :-1], out[:, -1]
 
 
@@ -49,15 +38,14 @@ def compact_query(mask, capacity, *, translate=False, interpret=None):
     """Single-HBM-pass drop-in for `backend.compact`: (idx, count), plus
     the key→slot translation vector when `translate`."""
     return compact(mask, int(capacity), translate=translate,
-                   interpret=resolve_interpret(interpret))
+                   interpret=interpret)
 
 
 def compact_pred_query(cols, scalars, pred_fn, capacity, *, translate=False,
                        interpret=None):
     """Fused filter → compact: predicate evaluated in-kernel."""
     return compact_pred(cols, scalars, pred_fn, int(capacity),
-                        translate=translate,
-                        interpret=resolve_interpret(interpret))
+                        translate=translate, interpret=interpret)
 
 
 def selective_agg_query(cols, scalars, pred_fn, value_fns, gidx_fn,
@@ -72,5 +60,5 @@ def selective_agg_query(cols, scalars, pred_fn, value_fns, gidx_fn,
 
     sums, total = selective_filter_agg(
         cols, scalars, pred_fn, vals_fn, gidx_fn, a + 1, n_groups,
-        interpret=resolve_interpret(interpret))
+        interpret=interpret)
     return sums[:, :-1], sums[:, -1], total

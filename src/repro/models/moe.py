@@ -137,7 +137,6 @@ def moe_ffn(x, p, cfg, ctx):
         return run(x, p)
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     dp = ctx.dp_axes
     if b % ctx.dp_size != 0:
@@ -156,9 +155,9 @@ def moe_ffn(x, p, cfg, ctx):
             "w_up": P(None, ctx.tp_axis),
             "w_down": P(ctx.tp_axis, None),
         }
-    return shard_map(
+    return jax.shard_map(
         run, mesh=ctx.mesh,
         in_specs=(P(dp, None, None), specs_p),
         out_specs=P(dp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, p)

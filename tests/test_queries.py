@@ -1,10 +1,10 @@
 """End-to-end correctness: every engine configuration must produce the
 same result as the interpreted Volcano oracle for every TPC-H query."""
-import numpy as np
 import pytest
 
 from repro.core import CompiledQuery, VolcanoEngine, preset
-from repro.relational.queries import QUERIES
+from repro.core.volcano import assert_same
+from repro.relational.queries import QUERIES, SORT_INSENSITIVE
 
 CONFIGS = ["naive", "template", "tpch", "strdict", "opt"]
 
@@ -24,38 +24,6 @@ CONFIG_PARAMS = [
 def oracle(db):
     eng = VolcanoEngine(db)
     return {name: eng.execute(fn()) for name, fn in QUERIES.items()}
-
-
-def canon(res: dict[str, np.ndarray], sort: bool) -> dict[str, np.ndarray]:
-    """Canonicalize: round floats, optionally sort rows by all columns."""
-    out = {}
-    names = sorted(res)
-    if not sort:
-        return {k: res[k] for k in names}
-    keys = []
-    for k in names:
-        v = res[k]
-        keys.append(np.round(v.astype(np.float64), 2) if v.dtype.kind == "f" else v)
-    order = np.lexsort(tuple(reversed(keys)))
-    return {k: res[k][order] for k in names}
-
-
-def assert_same(a: dict, b: dict, sort_insensitive: bool):
-    assert set(a) == set(b), f"columns differ: {set(a)} vs {set(b)}"
-    ca, cb = canon(a, sort_insensitive), canon(b, sort_insensitive)
-    for k in ca:
-        va, vb = ca[k], cb[k]
-        assert len(va) == len(vb), f"{k}: {len(va)} vs {len(vb)} rows"
-        if va.dtype.kind == "f" or vb.dtype.kind == "f":
-            np.testing.assert_allclose(
-                va.astype(np.float64), vb.astype(np.float64),
-                rtol=2e-3, atol=1e-2, err_msg=k)
-        else:
-            np.testing.assert_array_equal(va, vb, err_msg=k)
-
-
-# Queries whose final ordering can differ under float ties — compare as sets.
-SORT_INSENSITIVE = {"q10", "q18", "q3"}
 
 
 @pytest.mark.parametrize("config", CONFIG_PARAMS)

@@ -143,6 +143,8 @@ def test_cold_serve_is_oracle_with_zero_staging(db):
         assert cache.stats.tier_hits == {"oracle": 1}
         assert cache.stats.misses == 1
         gate.set()
+        assert cache.await_promotion(plan, OPT, defaults, timeout=120)
+        assert cache.stats.promote_failures == 0
     finally:
         cache.close()
 
@@ -185,6 +187,7 @@ def test_promotion_is_deduplicated(db):
         assert cache.stats.compiles == 1
         assert cache.stats.misses == 1
         assert cache.stats.hits >= 7
+        assert cache.stats.promote_failures == 0
     finally:
         cache.close()
 
@@ -198,6 +201,7 @@ def test_promote_through_builds_interpret_rung(db):
         # two rungs landed: interpret then compiled
         assert cache.stats.promotions == 2
         assert cache.stats.compiles == 2
+        assert cache.stats.promote_failures == 0
     finally:
         cache.close()
 
@@ -337,6 +341,7 @@ def test_tiered_server_serves_cold_then_promotes(db, tmp_path):
         res2 = srv.submit(plan_fn(), defaults).result(timeout=120)
         assert_same(res2, oracle_res, False)
         assert srv.stats.tier_served.get("compiled", 0) >= 1
+        assert srv.cache.stats.promote_failures == 0
     finally:
         srv.close()
     assert os.path.exists(path)
@@ -352,6 +357,7 @@ def test_tiered_server_serves_cold_then_promotes(db, tmp_path):
         assert_same(res, oracle_res, False)
         # request 1 after prewarm runs on the target tier, not the oracle
         assert srv2.stats.tier_served == {"compiled": 1}
+        assert srv2.cache.stats.promote_failures == 0
     finally:
         srv2.close()
 
@@ -373,6 +379,8 @@ def test_tiered_cache_run_many_skips_pad_accounting(db):
         # the oracle executes bindings one by one: no pow2 bucket, no
         # padded-slot accounting (3 -> bucket 4 would charge 1)
         assert cache.stats.padded_slots == 0
+        assert cache.await_promotion(plan, OPT, defaults, timeout=120)
+        assert cache.stats.promote_failures == 0
     finally:
         cache.close()
 
