@@ -86,6 +86,25 @@ def run(out=print) -> dict:
     out(csv("kernels/filter_agg/arith_intensity", 0.0,
             f"{flops_tile / bytes_tile:.2f} flop/byte"))
 
+    # gather_join @ nation-join shape: K=25 parent rows, 3 columns
+    k, c = 25, 3
+    fk = jnp.asarray(rng.integers(0, k, n), dtype=jnp.int32)
+    table = jnp.asarray(rng.normal(size=(k, c)), dtype=jnp.float32)
+    got = ops.gather_join(fk, table, tile=1024)
+    want = ref.gather_join_ref(fk, table)
+    err = float(jnp.max(jnp.abs(got - want)))
+    results["gather_join"] = {"max_err": err}
+    out(csv("kernels/gather_join/max_err", 0.0, f"{err:.2e}"))
+
+    # masked_topk @ Q3-like shape
+    vals1 = jnp.asarray(rng.permutation(n).astype(np.float32))
+    mask1 = jnp.asarray(rng.random(n) < 0.5)
+    tv, ti = ops.masked_topk(vals1, mask1, 10, tile=4096)
+    wv, wi = ref.masked_topk_ref(vals1, mask1, 10)
+    ok = bool(jnp.all(tv == wv))
+    results["masked_topk"] = {"exact": ok}
+    out(csv("kernels/masked_topk/exact_match", 0.0, str(ok)))
+
     # ---- single-pass compaction + the fused selective pipeline ----
 
     # compact @ selectivity sweep: validate, model HBM traffic

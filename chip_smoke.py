@@ -1,8 +1,8 @@
 """End-to-end smoke run of the query engine on a TPU, checked against the
 Volcano oracle.
 
-    python chip_smoke.py [--sf 1] [--seed 0]     # one chip
-    python chip_smoke.py --chips 4 [--sf 4]      # the sharded path only
+    python chip_smoke.py [--seed 0]              # one chip, SF 1
+    python chip_smoke.py --chips 4 [--seed 0]    # the sharded path only, SF 4
 
 One chip, at TPC-H SF 1 generated from `--seed`:
 
@@ -11,7 +11,10 @@ One chip, at TPC-H SF 1 generated from `--seed`:
   2. every `PARAM_QUERIES` template with several bindings submitted
      together, so that one coalesced `run_many` window serves them;
   3. the `opt-pallas` rung on q3, q6, q12 and q17, whose compiled programs
-     must contain the Pallas kernel (`tpu_custom_call`).
+     must contain the Pallas kernel (`tpu_custom_call`);
+  4. every kernel under `repro.kernels` against its jnp oracle
+     (`kernels/ref.py`) on the lineitem columns, compiled (not
+     interpreted).
 
 `--chips 4` runs only the sharded path: every `QUERIES` entry at
 `shards=4` through a `QueryServer`, with the partitioned inputs checked to
@@ -59,6 +62,89 @@ def _check(name, got, want, failures, out):
 def _peak_bytes(device):
     stats = device.memory_stats() or {}
     return stats.get("peak_bytes_in_use")
+
+
+def _same(name, got, want, failures, out, rtol=0.0, atol=0.0):
+    import numpy as np
+
+    try:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=rtol, atol=atol)
+    except AssertionError as e:
+        failures.append(name)
+        out(f"MISMATCH {name}: {str(e).strip()[:400]}")
+
+
+def check_kernels(db, seed: int = 0, out=print) -> list:
+    """Every Pallas kernel against its jnp oracle on the lineitem columns,
+    with a q6-like predicate.  Aggregated values are integers whose
+    per-group sums stay below 2**24, so every sum is exact in f32 and
+    compared exactly.  Returns the names of the cases that differed."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops, ref
+    from repro.relational import days
+
+    li = db.table("lineitem")
+    n = li.nrows
+    cols = {"d": jnp.asarray(li.col("l_shipdate")),
+            "q": jnp.asarray(li.col("l_quantity"))}
+    scalars = [jnp.int32(days("1994-01-01")), jnp.int32(days("1995-01-01")),
+               jnp.float32(24.0)]
+
+    def pred(c, s):
+        return (c["d"] >= s[0]) & (c["d"] < s[1]) & (c["q"] < s[2])
+
+    def vals(c, s):
+        return [c["q"], c["q"] * (c["d"] % 7).astype(jnp.float32)]
+
+    def gidx(c, s):
+        return c["d"] % 64
+
+    mask = pred(cols, scalars)
+    g2048, v2 = cols["d"] % 2048, jnp.stack(vals(cols, scalars), 1)
+    cap = 1 << 18
+    widx, wcount = ref.compact_ref(mask, cap)
+    wslot = ref.slot_of_ref(mask)
+    failures: list = []
+    cases = {
+        "compact": (lambda: ops.compact(mask, cap), (widx, wcount)),
+        "compact_translate": (lambda: ops.compact_translate(mask, cap),
+                              (widx, wcount, wslot)),
+        "compact_pred": (
+            lambda: ops.compact_pred(cols, scalars, pred, cap,
+                                     translate=True), (widx, wcount, wslot)),
+        "filter_agg": (lambda: (ops.filter_agg(mask, g2048, v2, 2048),),
+                       (ref.filter_agg_ref(mask, g2048, v2, 2048),)),
+        "selective_filter_agg": (
+            lambda: ops.selective_filter_agg(cols, scalars, pred, vals, gidx,
+                                             2, 64, cap, True),
+            ref.selective_filter_agg_ref(cols, scalars, pred, vals, gidx, 2,
+                                         64, cap, True)),
+    }
+    fk = jnp.asarray(li.col("l_suppkey")) % 25
+    table = jax.random.normal(jax.random.key(seed), (25, 3), jnp.float32)
+    price = jnp.asarray(li.col("l_extendedprice"))
+    cases["gather_join"] = (lambda: (ops.gather_join(fk, table),),
+                            (ref.gather_join_ref(fk, table),))
+    cases["masked_topk"] = (lambda: ops.masked_topk(price, mask, 10),
+                            ref.masked_topk_ref(price, mask, 10))
+    out(f"kernels vs oracle: {int(wcount)} of {n} rows pass the predicate, "
+        f"compaction capacity {cap}")
+    for name, (run, want) in cases.items():
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(run())
+        out(f"kernel {name}: {n} rows, {time.perf_counter() - t0:.3f} s "
+            f"with compile")
+        if len(got) != len(want):
+            failures.append(f"kernel/{name}/arity")
+            continue
+        # the one-hot gather runs on the MXU: the kernel tests' tolerance
+        tol = dict(rtol=1e-5, atol=1e-6) if name == "gather_join" else {}
+        for i, (g, w) in enumerate(zip(got, want)):
+            _same(f"kernel/{name}/{i}", g, w, failures, out, **tol)
+    return failures
 
 
 def smoke(sf: float = 1.0, seed: int = 0, out=print) -> dict:
@@ -135,6 +221,9 @@ def smoke(sf: float = 1.0, seed: int = 0, out=print) -> dict:
         _check(f"{name}/opt-pallas", got, oracle.execute(QUERIES[name]()),
                failures, out)
 
+    # 4. every kernel against its jnp oracle
+    failures += check_kernels(db, seed, out)
+
     peak = _peak_bytes(jax.devices()[0])
     out(f"device peak_bytes_in_use={peak}")
     return {"failures": failures, "timings": timings, "peak_bytes": peak}
@@ -190,8 +279,6 @@ def smoke_sharded(n: int, sf: float = 4.0, seed: int = 0,
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--sf", type=float, default=None,
-                    help="TPC-H scale factor (default 1, or 4 with --chips 4)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
@@ -207,9 +294,9 @@ def main() -> int:
 
     print(f"compile cache: {enable_compilation_cache()}")
     if args.chips == 1:
-        summary = smoke(args.sf or 1.0, args.seed)
+        summary = smoke(1.0, args.seed)
     else:
-        summary = smoke_sharded(args.chips, args.sf or 4.0, args.seed)
+        summary = smoke_sharded(args.chips, 4.0, args.seed)
     if summary["failures"]:
         print(f"chip_smoke FAILED: {summary['failures']}", file=sys.stderr)
         return 1

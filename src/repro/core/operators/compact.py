@@ -92,8 +92,8 @@ def stage(c: ir.Compact, ctx: StageCtx, defer: bool = False) -> Frame:
         from repro.kernels import ops as kops
 
         cols_d, scalars, pnames = operands
-        res = kops.compact_pred_query(
-            cols_d, scalars, fu.make_tile_fn(pred, pnames), cap,
+        res = kops.compact_pred(
+            cols_d, scalars, fu.make_tile_fn(pred, pnames), int(cap),
             translate=c.translate, interpret=s.pallas_interpret)
         idx, count = res[0], res[1]
         if c.translate:
@@ -103,8 +103,8 @@ def stage(c: ir.Compact, ctx: StageCtx, defer: bool = False) -> Frame:
         if use_k:
             from repro.kernels import ops as kops
 
-            res = kops.compact_query(mask, cap, translate=c.translate,
-                                     interpret=s.pallas_interpret)
+            res = kops.compact(mask, int(cap), translate=c.translate,
+                               interpret=s.pallas_interpret)
             idx, count = res[0], res[1]
             if c.translate:
                 slot = res[2]

@@ -1,6 +1,8 @@
 from repro.kernels import ops, ref
 from repro.kernels.ops import (compact, compact_pred, compact_translate,
-                               filter_agg, selective_filter_agg)
+                               filter_agg, gather_join, masked_topk,
+                               selective_filter_agg)
 
-__all__ = ["ops", "ref", "filter_agg", "compact", "compact_translate",
-           "compact_pred", "selective_filter_agg"]
+__all__ = ["ops", "ref", "filter_agg", "gather_join", "masked_topk",
+           "compact", "compact_translate", "compact_pred",
+           "selective_filter_agg"]

@@ -1,4 +1,4 @@
-"""Query-engine entry points to the Pallas kernel (`kernels.pipeline`).
+"""Query-engine entry points to the Pallas kernels.
 
 `interpret` selects the Pallas execution mode: `None` (the default)
 compiles the kernel when JAX's backend is a TPU and runs the (slow,
@@ -6,20 +6,21 @@ validation-only) Pallas interpreter elsewhere; an explicit bool forces
 either mode (`Settings.pallas_interpret` threads the engine-level override
 through).  `filter_agg_query` is the integration point used by
 `repro.core.operators.agg` when `Settings.use_pallas` is on;
-`compact_query` / `compact_pred_query` / `selective_agg_query` are the
-corresponding single-pass entry points for `operators.compact` and the
-fused selective pipeline.
+`selective_agg_query` is the fused selective pipeline's; `operators.compact`
+calls `compact` / `compact_pred` directly.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.gather_join import gather_join
 from repro.kernels.pipeline import (compact, compact_pred, compact_translate,
                                     filter_agg, resolve_interpret,
                                     selective_filter_agg)
+from repro.kernels.topk import masked_topk
 
-__all__ = ["filter_agg", "filter_agg_query", "compact", "compact_translate",
-           "compact_pred", "compact_query", "compact_pred_query",
+__all__ = ["filter_agg", "filter_agg_query", "gather_join", "masked_topk",
+           "compact", "compact_translate", "compact_pred",
            "selective_filter_agg", "selective_agg_query",
            "resolve_interpret"]
 
@@ -32,20 +33,6 @@ def filter_agg_query(mask, gidx, value_cols, n_groups, *, interpret=None):
     out = filter_agg(mask, gidx.astype(jnp.int32), vals, n_groups,
                      interpret=interpret)
     return out[:, :-1], out[:, -1]
-
-
-def compact_query(mask, capacity, *, translate=False, interpret=None):
-    """Single-HBM-pass drop-in for `backend.compact`: (idx, count), plus
-    the key→slot translation vector when `translate`."""
-    return compact(mask, int(capacity), translate=translate,
-                   interpret=interpret)
-
-
-def compact_pred_query(cols, scalars, pred_fn, capacity, *, translate=False,
-                       interpret=None):
-    """Fused filter → compact: predicate evaluated in-kernel."""
-    return compact_pred(cols, scalars, pred_fn, int(capacity),
-                        translate=translate, interpret=interpret)
 
 
 def selective_agg_query(cols, scalars, pred_fn, value_fns, gidx_fn,

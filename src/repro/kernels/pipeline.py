@@ -149,7 +149,16 @@ def _row_kernel(*refs, names, n_scalars: int, pred_fn, vals_fn, gidx_fn,
     cnt_ref[0] = jax.lax.fori_loop(0, rows, row, cnt_ref[0])
 
 
-def _lanes(x, n_t: int):
+def tile_rows(tile: int) -> int:
+    """128-lane rows in a block of `tile` elements.  A TPU block holds
+    whole (8, 128) vreg tiles, so `tile` must be a multiple of 1024."""
+    if tile <= 0 or tile % (8 * LANES):
+        raise ValueError(f"tile={tile} is not a positive multiple of "
+                         f"{8 * LANES}")
+    return tile // LANES
+
+
+def lanes(x, n_t: int):
     """(n,) column -> lane-dense (n_t / 128, 128), 32-bit, zero padded."""
     x = jnp.asarray(x)
     if x.dtype.itemsize != 4:
@@ -166,10 +175,10 @@ def _pipeline(cols: dict, scalars: list, pred_fn, vals_fn, gidx_fn,
     n = jnp.shape(cols[names[0]])[0]
     # small inputs shrink the block, down to the 8-row sublane minimum
     need = -(-max(n, 1) // LANES)
-    rows = max(8, min(tile // LANES, -(-need // 8) * 8))
+    rows = max(8, min(tile_rows(tile), -(-need // 8) * 8))
     block = rows * LANES
     n_t = -(-max(n, 1) // block) * block
-    ins = [_lanes(cols[nm], n_t) for nm in names]
+    ins = [lanes(cols[nm], n_t) for nm in names]
     ins += [jnp.asarray(s).reshape(1) for s in scalars]
     row_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     smem = pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM)

@@ -10,6 +10,13 @@ def filter_agg_ref(mask, gidx, vals, n_groups):
     return jax.ops.segment_sum(data, gidx, num_segments=n_groups)
 
 
+def gather_join_ref(fk, table):
+    k = table.shape[0]
+    ok = (fk >= 0) & (fk < k)
+    out = table[jnp.clip(fk, 0, k - 1)]
+    return jnp.where(ok[:, None], out, 0.0)
+
+
 def compact_ref(mask, capacity):
     """(idx, count) oracle matching `backend.compact`'s contract: the
     first min(count, capacity) slots are the valid row ids in order, pad
@@ -50,3 +57,13 @@ def selective_filter_agg_ref(cols, scalars, pred_fn, vals_fn, gidx_fn,
     if translate:
         out.append(slot_of_ref(m))
     return tuple(out)
+
+
+def masked_topk_ref(vals, mask, k):
+    neg = jnp.float32(-3.0e38)
+    v = jnp.where(mask, vals, neg)
+    if k > v.shape[0]:
+        v = jnp.pad(v, (0, k - v.shape[0]), constant_values=neg)
+    topv, topi = jax.lax.top_k(v, k)
+    topi = jnp.where(topv <= neg, -1, topi)
+    return topv, topi

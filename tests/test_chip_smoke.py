@@ -18,6 +18,7 @@ def test_smoke_checks_match_oracle_on_cpu():
     lines = []
     summary = chip_smoke.smoke(0.01, seed=0, out=lines.append)
     assert summary["failures"] == [], "\n".join(lines)
+    assert sum(line.startswith("kernel ") for line in lines) == 7
     from repro.relational.queries import QUERIES
 
     assert set(QUERIES) <= set(summary["timings"])

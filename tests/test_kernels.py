@@ -26,6 +26,42 @@ def test_filter_agg_matches_ref(n, n_groups, n_aggs):
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("n", [3, 513, 4096])
+@pytest.mark.parametrize("k,c", [(5, 1), (25, 4), (640, 3)])
+def test_gather_join_matches_ref(n, k, c):
+    rng = np.random.default_rng(n + k + c)
+    fk = jnp.asarray(rng.integers(0, k, n), dtype=jnp.int32)
+    table = jnp.asarray(rng.normal(size=(k, c)), dtype=jnp.float32)
+    out = ops.gather_join(fk, table, tile=1024)
+    want = ref.gather_join_ref(fk, table)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n", [10, 1000, 9001])
+@pytest.mark.parametrize("k", [1, 10, 32])
+def test_masked_topk_matches_ref(n, k):
+    rng = np.random.default_rng(n + k)
+    # distinct values so ordering is unambiguous
+    vals = jnp.asarray(rng.permutation(n).astype(np.float32))
+    mask = jnp.asarray(rng.random(n) < 0.7)
+    tv, ti = ops.masked_topk(vals, mask, k, tile=2048)
+    wv, wi = ref.masked_topk_ref(vals, mask, k)
+    np.testing.assert_allclose(tv, wv, rtol=0, atol=0)
+    np.testing.assert_array_equal(np.asarray(ti), np.asarray(wi))
+
+
+@pytest.mark.parametrize("tile", [64, 1000, 1536])
+def test_tile_must_hold_whole_vreg_tiles(tile):
+    """A block is whole (8, 128) tiles: any other tile is refused, never
+    silently rounded to a different grid."""
+    mask = jnp.ones((4096,), bool)
+    with pytest.raises(ValueError, match="multiple of 1024"):
+        ops.compact(mask, 16, tile=tile)
+    with pytest.raises(ValueError, match="multiple of 1024"):
+        ops.gather_join(jnp.zeros((4096,), jnp.int32),
+                        jnp.ones((4, 2), jnp.float32), tile=tile)
+
+
 # ---------------------------------------------------------------------------
 # single-pass stream compaction (+ key→slot translation)
 # ---------------------------------------------------------------------------
@@ -48,7 +84,7 @@ def test_compact_matches_ref(n, cap, p):
 def test_compact_translate_matches_ref(n, cap):
     rng = np.random.default_rng(n + cap)
     mask = jnp.asarray(rng.random(n) < 0.3)
-    idx, count, slot = ops.compact_translate(mask, cap, tile=512)
+    idx, count, slot = ops.compact_translate(mask, cap, tile=1024)
     widx, wcount = ref.compact_ref(mask, cap)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(widx))
     np.testing.assert_array_equal(np.asarray(slot),
@@ -76,30 +112,33 @@ def test_compact_lane_boundaries(pattern, cap):
 def test_compact_overflow_keeps_exact_count():
     """count is the cumsum total, NOT clipped at capacity — the excess IS
     the overflow signal and its magnitude drives re-planning."""
-    mask = jnp.ones((300,), dtype=bool)
-    idx, count = ops.compact(mask, 16, tile=128)
-    assert int(count) == 300
+    mask = jnp.ones((3000,), dtype=bool)
+    idx, count = ops.compact(mask, 16, tile=1024)
+    assert int(count) == 3000
     np.testing.assert_array_equal(np.asarray(idx), np.arange(16))
 
 
 def test_compact_vmapped():
     """vmap over batched masks (the run_many path stages kernels under
-    vmap): per-slot results must equal per-slot scalar calls."""
+    vmap): per-slot results must equal per-slot scalar calls and the
+    oracle, with the running count carried over three grid steps."""
     import jax
 
     rng = np.random.default_rng(0)
-    masks = jnp.asarray(rng.random((4, 200)) < 0.25)
-    bidx, bcount = jax.vmap(lambda m: ops.compact(m, 32, tile=64))(masks)
+    masks = jnp.asarray(rng.random((4, 3000)) < 0.25)
+    bidx, bcount = jax.vmap(lambda m: ops.compact(m, 1024, tile=1024))(masks)
     for i in range(4):
-        idx, count = ops.compact(masks[i], 32, tile=64)
+        idx, count = ops.compact(masks[i], 1024, tile=1024)
+        widx, wcount = ref.compact_ref(masks[i], 1024)
         np.testing.assert_array_equal(np.asarray(bidx[i]), np.asarray(idx))
-        assert int(bcount[i]) == int(count)
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(widx))
+        assert int(bcount[i]) == int(count) == int(wcount)
 
 
 def test_compact_pred_matches_ref():
     """In-kernel predicate evaluation from named column blocks + scalars."""
     rng = np.random.default_rng(3)
-    n = 777
+    n = 2777
     cols = {"a": jnp.asarray(rng.normal(size=n), jnp.float32),
             "b": jnp.asarray(rng.integers(0, 10, n), jnp.int32)}
     scalars = [jnp.float32(0.2)]
@@ -107,10 +146,10 @@ def test_compact_pred_matches_ref():
     def pred(c, s):
         return (c["a"] < s[0]) & (c["b"] >= 3)
 
-    idx, count, slot = ops.compact_pred(cols, scalars, pred, 128,
-                                        tile=256, translate=True)
+    idx, count, slot = ops.compact_pred(cols, scalars, pred, 1024,
+                                        tile=1024, translate=True)
     mask = pred(cols, scalars)
-    widx, wcount = ref.compact_ref(mask, 128)
+    widx, wcount = ref.compact_ref(mask, 1024)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(widx))
     np.testing.assert_array_equal(np.asarray(slot),
                                   np.asarray(ref.slot_of_ref(mask)))
@@ -139,7 +178,7 @@ def test_selective_filter_agg_matches_ref(n, n_groups, capacity):
     cols, scalars, pred, vals, gidx = _pipeline_case(n, n_groups, n)
     translate = capacity > 0
     got = ops.selective_filter_agg(cols, scalars, pred, vals, gidx, 2,
-                                   n_groups, capacity, translate, tile=512)
+                                   n_groups, capacity, translate, tile=1024)
     want = ref.selective_filter_agg_ref(cols, scalars, pred, vals, gidx, 2,
                                         n_groups, capacity, translate)
     assert len(got) == len(want)
@@ -153,19 +192,19 @@ def test_selective_filter_agg_matches_ref(n, n_groups, capacity):
 def test_selective_filter_agg_empty_and_full():
     """count == 0 (no row passes) and all-pass both behave: zero sums /
     identity compaction respectively."""
-    n = 130
+    n = 2600
     cols = {"x": jnp.asarray(np.arange(n), jnp.float32)}
     scalars = []
     vals = lambda c, s: [c["x"]]
     never = lambda c, s: c["x"] < -1.0
     sums, count, idx = ops.selective_filter_agg(
-        cols, scalars, never, vals, None, 1, 1, capacity=16, tile=64)
+        cols, scalars, never, vals, None, 1, 1, capacity=16, tile=1024)
     assert int(count) == 0
     assert float(np.asarray(sums).sum()) == 0.0
     np.testing.assert_array_equal(np.asarray(idx), np.zeros(16))
     always = lambda c, s: c["x"] >= 0.0
     sums, count, idx = ops.selective_filter_agg(
-        cols, scalars, always, vals, None, 1, 1, capacity=256, tile=64)
+        cols, scalars, always, vals, None, 1, 1, capacity=4096, tile=1024)
     assert int(count) == n
     np.testing.assert_allclose(float(np.asarray(sums)[0, 0]),
                                float(np.arange(n).sum()), rtol=1e-6)
@@ -177,27 +216,28 @@ def test_selective_filter_agg_empty_and_full():
 # ---------------------------------------------------------------------------
 
 @hsettings(max_examples=25, deadline=None)
-@given(st.integers(1, 400), st.integers(1, 12), st.integers(0, 2**31 - 1))
+@given(st.integers(1, 4000), st.integers(1, 12), st.integers(0, 2**31 - 1))
 def test_filter_agg_total_invariant(n, g, seed):
     """Sum over groups == masked sum over rows (conservation)."""
     rng = np.random.default_rng(seed)
     mask = jnp.asarray(rng.random(n) < 0.5)
     gidx = jnp.asarray(rng.integers(0, g, n), dtype=jnp.int32)
     vals = jnp.asarray(rng.normal(size=(n, 2)), dtype=jnp.float32)
-    out = ops.filter_agg(mask, gidx, vals, g, tile=128)
+    out = ops.filter_agg(mask, gidx, vals, g, tile=1024)
     total = np.where(np.asarray(mask)[:, None], np.asarray(vals), 0).sum(0)
     np.testing.assert_allclose(np.asarray(out).sum(0), total, rtol=1e-4,
                                atol=1e-4)
 
 
 @hsettings(max_examples=25, deadline=None)
-@given(st.integers(1, 500), st.integers(3, 64), st.integers(0, 2**31 - 1))
+@given(st.integers(1, 4000), st.integers(3, 2048), st.integers(0, 2**31 - 1))
 def test_compact_prefix_invariant(n, cap, seed):
     """The emitted prefix is exactly the first min(count, cap) valid row
     ids in ascending order, and slot_of inverts it (slot_of[idx[i]] == i)."""
     rng = np.random.default_rng(seed)
     mask = rng.random(n) < rng.random()
-    idx, count, slot = ops.compact_translate(jnp.asarray(mask), cap, tile=64)
+    idx, count, slot = ops.compact_translate(jnp.asarray(mask), cap,
+                                             tile=1024)
     idx, slot = np.asarray(idx), np.asarray(slot)
     valid_ids = np.flatnonzero(mask)
     k = min(int(count), cap)
@@ -205,3 +245,15 @@ def test_compact_prefix_invariant(n, cap, seed):
     np.testing.assert_array_equal(idx[k:], 0)
     np.testing.assert_array_equal(slot[mask], np.arange(len(valid_ids)))
     assert (slot[~mask] == -1).all()
+
+
+@hsettings(max_examples=25, deadline=None)
+@given(st.integers(1, 3000), st.integers(2, 50), st.integers(0, 2**31 - 1))
+def test_gather_join_row_identity(n, k, seed):
+    """Gathering the identity table returns one-hot rows that sum to 1."""
+    rng = np.random.default_rng(seed)
+    fk = jnp.asarray(rng.integers(0, k, n), dtype=jnp.int32)
+    table = jnp.eye(k, dtype=jnp.float32)
+    out = np.asarray(ops.gather_join(fk, table, tile=1024))
+    np.testing.assert_allclose(out.sum(1), np.ones(n), atol=1e-6)
+    np.testing.assert_array_equal(out.argmax(1), np.asarray(fk))

@@ -30,10 +30,9 @@ def kernel_calls(monkeypatch):
             return fn(*a, **k)
         return g
 
-    monkeypatch.setattr(kops, "compact_query",
-                        wrap("compact", kops.compact_query))
-    monkeypatch.setattr(kops, "compact_pred_query",
-                        wrap("compact_pred", kops.compact_pred_query))
+    monkeypatch.setattr(kops, "compact", wrap("compact", kops.compact))
+    monkeypatch.setattr(kops, "compact_pred",
+                        wrap("compact_pred", kops.compact_pred))
     monkeypatch.setattr(kops, "selective_agg_query",
                         wrap("selective_agg", kops.selective_agg_query))
     monkeypatch.setattr(kops, "filter_agg_query",

@@ -1,4 +1,4 @@
-"""The Pallas kernel and an `opt-pallas` query program compiled for a
+"""The Pallas kernels and an `opt-pallas` query program compiled for a
 described TPU v5e (no chip attached): the TPU compiler refuses what the
 interpreter accepts, and a program must fit one chip's 16 GB.
 
@@ -47,8 +47,10 @@ def one_chip(topo):
     cc.reset_cache()
 
 
-def _shape(one_chip, dtype, n=ROWS):
-    return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+def _arg(one_chip, spec):
+    """`dtype` -> a column of ROWS; `(dtype, shape)` -> that shape."""
+    dtype, shape = spec if isinstance(spec, tuple) else (spec, (ROWS,))
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
 def _pred(c, s):
@@ -59,7 +61,7 @@ def _vals(c, s):
     return [c["p"] * c["q"], c["p"]]
 
 
-# name -> (function of the kernel inputs, input dtypes)
+# name -> (function of the kernel inputs, input specs for `_arg`)
 KERNELS = {
     "compact": (lambda m: ops.compact(m, 1 << 20, interpret=False),
                 [jnp.bool_]),
@@ -73,7 +75,7 @@ KERNELS = {
         [jnp.int32, jnp.float32]),
     "filter_agg_4096_groups": (
         lambda m, g, v: ops.filter_agg(m, g, v, 4096, interpret=False),
-        [jnp.bool_, jnp.int32, (jnp.float32, 4)]),
+        [jnp.bool_, jnp.int32, (jnp.float32, (ROWS, 4))]),
     "selective_filter_agg": (
         lambda d, q, p, lo, hi, qm: ops.selective_filter_agg(
             {"d": d, "q": q, "p": p}, [lo, hi, qm], _pred, _vals, None, 2,
@@ -85,19 +87,22 @@ KERNELS = {
             lambda c, s: c["d"] % 7, 2, 7, capacity=1 << 18,
             translate=True, interpret=False),
         [jnp.int32, jnp.float32, jnp.float32]),
+    "gather_join_nation": (
+        lambda fk, t: ops.gather_join(fk, t, interpret=False),
+        [jnp.int32, (jnp.float32, (25, 3))]),
+    "gather_join_640_keys": (
+        lambda fk, t: ops.gather_join(fk, t, interpret=False),
+        [jnp.int32, (jnp.float32, (640, 4))]),
+    "masked_topk_10": (
+        lambda v, m: ops.masked_topk(v, m, 10, interpret=False),
+        [jnp.float32, jnp.bool_]),
 }
 
 
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, name):
-    fn, dtypes = KERNELS[name]
-    args = []
-    for dt in dtypes:
-        if isinstance(dt, tuple):
-            args.append(jax.ShapeDtypeStruct((ROWS, dt[1]), dt[0],
-                                             sharding=one_chip))
-        else:
-            args.append(_shape(one_chip, dt))
+    fn, specs = KERNELS[name]
+    args = [_arg(one_chip, spec) for spec in specs]
     if name.startswith(("compact_pred", "selective")):
         args += [jax.ShapeDtypeStruct((), t, sharding=one_chip)
                  for t in (jnp.int32, jnp.int32, jnp.float32)]
