@@ -152,7 +152,6 @@ def _trace(db, cache: PlanCache, hardened: bool, kind: str, rate: float,
         "throughput_per_s": st.completed / wall if wall > 0 else 0.0,
         "p50_s": float(lat_arr[int(0.50 * (len(lat_arr) - 1))]),
         "p99_s": float(lat_arr[int(0.99 * (len(lat_arr) - 1))]),
-        "hist_p99_s": st.latency.p99(),
         "oracle_drift": drift[0],
         "degraded_served": cache.stats.degraded - degraded_before,
     }

@@ -55,6 +55,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import ir
 from repro.core.backend import JaxBackend, NumpyBackend
 from repro.core.expr import Param
@@ -437,30 +438,39 @@ class CompiledQuery:
         import jax
 
         self.n_executions += 1
-        out, mask, counts = self._jitted(self.bind(params))
-        if self.compaction_points or self.measure_points:
+        with obs.span("query.bind"):
+            inputs = self.bind(params)
+        with obs.span("query.dispatch"):
+            out, mask, counts = self._jitted(inputs)
+        with obs.span("query.fetch"):
             # sharded programs report an (n_shards,) vector per point;
             # overflow and the scalar feedback both key off the worst shard
             vecs = {pid: np.atleast_1d(np.asarray(c)).reshape(-1)
                     for pid, c in counts.items()}
-            counts = {pid: int(v.max()) for pid, v in vecs.items()}
-            self._observe([counts])
-            if self.n_shards > 1:
-                self._observe_shards(vecs)
-            if any(c > self.point_caps[pid] for pid, c in counts.items()
-                   if pid in self.point_caps):
+            out = jax.tree.map(np.asarray, out)
+            mask = np.asarray(mask)
+        if self.compaction_points or self.measure_points:
+            with obs.span("query.feedback"):
+                counts = {pid: int(v.max()) for pid, v in vecs.items()}
+                self._observe([counts])
+                if self.n_shards > 1:
+                    self._observe_shards(vecs)
+                overflow = any(c > self.point_caps[pid]
+                               for pid, c in counts.items()
+                               if pid in self.point_caps)
+            if overflow:
                 # a capacity bucket overflowed: the compacted frames
                 # dropped rows, so the outputs are unusable — re-execute
                 # uncompacted; the twin's measure probes report every
                 # site's TRUE count, folded back for the feedback store
                 self.n_overflows += 1
-                twin = self._fallback_query()
-                res = twin.run(params)
-                self._merge_twin_observations(twin)
+                with obs.span("query.fallback"):
+                    twin = self._fallback_query()
+                    res = twin.run(params)
+                    self._merge_twin_observations(twin)
                 return res
-        out = jax.tree.map(np.asarray, out)
-        mask = np.asarray(mask)
-        return self._decode(out, mask)
+        with obs.span("query.decode"):
+            return self._decode(out, mask)
 
     def run_many(self, bindings_list) -> list[dict[str, np.ndarray]]:
         """Execute N bindings as ONE XLA dispatch (the vmapped program).
@@ -483,9 +493,14 @@ class CompiledQuery:
         import jax
 
         self.n_executions += 1
-        out, mask, counts = self._jitted_many(self.bind_many(bindings_list))
-        out = jax.tree.map(np.asarray, out)
-        mask = np.asarray(mask)
+        with obs.span("query.bind"):
+            inputs = self.bind_many(bindings_list)
+        with obs.span("query.dispatch"):
+            out, mask, counts = self._jitted_many(inputs)
+        with obs.span("query.fetch"):
+            out = jax.tree.map(np.asarray, out)
+            mask = np.asarray(mask)
+            counts = {pid: np.asarray(c) for pid, c in counts.items()}
         n_real = len(bindings_list)
         bad: list[int] = []
         if self.compaction_points or self.measure_points:
@@ -496,30 +511,33 @@ class CompiledQuery:
             # uncompacted-twin executions
             # per-point shapes: (B,) unsharded, (B, n_shards) sharded —
             # np.max over a slot's entry covers both
-            counts = {pid: np.asarray(c) for pid, c in counts.items()}
-            slot_counts = [{pid: int(np.max(v[i]))
-                            for pid, v in counts.items()}
-                           for i in range(n_real)]
-            self._observe(slot_counts)
-            if self.n_shards > 1 and counts:
-                self._observe_shards(
-                    {pid: np.atleast_1d(np.max(v[:n_real], axis=0))
-                     for pid, v in counts.items()})
-            bad = [i for i, sc in enumerate(slot_counts)
-                   if any(c > self.point_caps[pid] for pid, c in sc.items()
-                          if pid in self.point_caps)]
+            with obs.span("query.feedback"):
+                slot_counts = [{pid: int(np.max(v[i]))
+                                for pid, v in counts.items()}
+                               for i in range(n_real)]
+                self._observe(slot_counts)
+                if self.n_shards > 1 and counts:
+                    self._observe_shards(
+                        {pid: np.atleast_1d(np.max(v[:n_real], axis=0))
+                         for pid, v in counts.items()})
+                bad = [i for i, sc in enumerate(slot_counts)
+                       if any(c > self.point_caps[pid]
+                              for pid, c in sc.items()
+                              if pid in self.point_caps)]
         bad_set = set(bad)
-        results = [None if i in bad_set
-                   else self._decode({k: v[i] for k, v in out.items()},
-                                     mask[i])
-                   for i in range(n_real)]
+        with obs.span("query.decode"):
+            results = [None if i in bad_set
+                       else self._decode({k: v[i] for k, v in out.items()},
+                                         mask[i])
+                       for i in range(n_real)]
         if bad:
             # per-slot overflow: only the overflowing bindings re-execute
             # through the uncompacted twin (itself one vmapped dispatch)
             self.n_overflows += len(bad)
-            twin = self._fallback_query()
-            redo = twin.run_many([bindings_list[i] for i in bad])
-            self._merge_twin_observations(twin)
+            with obs.span("query.fallback"):
+                twin = self._fallback_query()
+                redo = twin.run_many([bindings_list[i] for i in bad])
+                self._merge_twin_observations(twin)
             for i, r in zip(bad, redo):
                 results[i] = r
         return results

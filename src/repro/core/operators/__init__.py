@@ -2,8 +2,15 @@
 function `stage(node, ctx, defer=False) -> Frame` over the shared
 `StageCtx`.  `repro.core.compile` is the driver that runs this dispatch
 twice (numpy collection walk, traced JAX walk) and wraps the result in a
-`CompiledQuery`."""
+`CompiledQuery`.
+
+Each operator stages under `jax.named_scope("op.<operator>")`, so the
+HLO it emits carries the operator in its `op_name` metadata, nested as
+the plan nests: a device op's innermost `op.*` scope names the operator
+that staged it."""
 from __future__ import annotations
+
+import jax
 
 from repro.core import ir
 from repro.core.operators import (agg, compact, exchange, join, limit,
@@ -28,7 +35,8 @@ def stage(node: ir.Plan, ctx: StageCtx, defer: bool = False) -> Frame:
     fn = _DISPATCH.get(type(node))
     if fn is None:
         raise TypeError(type(node))
-    return fn(node, ctx, defer)
+    with jax.named_scope(f"op.{type(node).__name__.lower()}"):
+        return fn(node, ctx, defer)
 
 
 __all__ = ["Binding", "Frame", "FrameEnv", "StageCtx", "frame_nrows",

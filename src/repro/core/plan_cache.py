@@ -45,6 +45,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import compile as compile_mod
 from repro.core import ir
 from repro.core import persist as persist_mod
@@ -350,9 +351,10 @@ class PlanCache:
             else:
                 fb = self._feedback_for(key[:-1], runtime)
                 est, observed = fb.est_params, fb.overrides
-        cq = CompiledQuery(plan if owned else copy.deepcopy(plan),
-                           self.db, settings, params=runtime,
-                           est_params=est, observed=observed)
+        with obs.span("cache.compile"):
+            cq = CompiledQuery(plan if owned else copy.deepcopy(plan),
+                               self.db, settings, params=runtime,
+                               est_params=est, observed=observed)
         cq._cache_key = key
         with self._lock:
             self.stats.compiles += 1
@@ -590,14 +592,15 @@ class PlanCache:
         adaptive-feedback step."""
         if not cq.compaction_points:
             return
-        with self._lock:
-            self.stats.compactions += n_execs
-            seen = self._overflow_seen.get(cq, 0)
-            delta = max(cq.n_overflows - seen, 0)
-            if delta:
-                self.stats.overflows += delta
-                self._overflow_seen[cq] = cq.n_overflows
-        self._feedback_step(cq, delta)
+        with obs.span("query.feedback"):
+            with self._lock:
+                self.stats.compactions += n_execs
+                seen = self._overflow_seen.get(cq, 0)
+                delta = max(cq.n_overflows - seen, 0)
+                if delta:
+                    self.stats.overflows += delta
+                    self._overflow_seen[cq] = cq.n_overflows
+            self._feedback_step(cq, delta)
 
     def _feedback_step(self, cq: CompiledQuery, overflow_delta: int) -> None:
         """Close the loop between runtime and planner: merge the entry's

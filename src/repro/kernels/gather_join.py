@@ -66,5 +66,6 @@ def gather_join(fk: jax.Array, table: jax.Array, *, tile: int = 1024,
         out_specs=pl.BlockSpec((c8, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((c8, n_t), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="gather_join",
     )(lanes(fk, n_t), table_t)
     return out[:c, :n].T

@@ -219,6 +219,7 @@ def _pipeline(cols: dict, scalars: list, pred_fn, vals_fn, gidx_fn,
         out_shape=out_shape,
         compiler_params=params,
         interpret=resolve_interpret(interpret),
+        name="pipeline",
     )(*ins)
     res = list(res)
     out = [res.pop(0)[0]]

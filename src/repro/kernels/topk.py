@@ -73,6 +73,7 @@ def masked_topk(vals: jax.Array, mask: jax.Array, k: int, *,
             jax.ShapeDtypeStruct((steps, 1, kp), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
+        name="masked_topk",
     )(lanes(vals.astype(jnp.float32), n_t), lanes(mask, n_t))
 
     flatv, flati = pv[:, 0, :k].reshape(-1), pi[:, 0, :k].reshape(-1)

@@ -1,4 +1,4 @@
-"""Admission control, typed serving errors, and serving telemetry.
+"""Admission control, typed serving errors, and the arrival-rate EMA.
 
 The overload-hardening layer of `QueryServer` (docs/architecture.md §10):
 
@@ -17,8 +17,6 @@ The overload-hardening layer of `QueryServer` (docs/architecture.md §10):
   * `RateEMA` — exponentially weighted arrival-interval tracker (the
     `StragglerStats` idiom pointed at arrivals instead of step times);
     drives the adaptive coalescing window.
-  * `LatencyHistogram` — log2-bucketed latency histogram with p50/p99
-    readout, embedded in `ServerStats`.
 """
 from __future__ import annotations
 
@@ -76,45 +74,6 @@ class RateEMA:
     def rate(self) -> float:
         """Smoothed arrivals per second (0.0 until two arrivals seen)."""
         return 1.0 / self.ema if self.count else 0.0
-
-
-@dataclasses.dataclass
-class LatencyHistogram:
-    """Log2-bucketed latency histogram: bucket i covers
-    [2^i, 2^(i+1)) microseconds, so p50/p99 readouts carry at most one
-    octave of quantization error — plenty for an overload dashboard, and
-    O(1) memory regardless of traffic."""
-    counts: list = dataclasses.field(default_factory=lambda: [0] * 32)
-    count: int = 0
-    total_s: float = 0.0
-
-    def observe(self, seconds: float) -> None:
-        us = max(seconds * 1e6, 1.0)
-        i = min(int(math.log2(us)), len(self.counts) - 1)
-        self.counts[i] += 1
-        self.count += 1
-        self.total_s += seconds
-
-    def quantile(self, q: float) -> float:
-        """Approximate quantile in seconds (geometric bucket midpoint)."""
-        if self.count == 0:
-            return 0.0
-        target = q * self.count
-        seen = 0
-        for i, c in enumerate(self.counts):
-            seen += c
-            if seen >= target:
-                return (2.0 ** (i + 0.5)) * 1e-6
-        return (2.0 ** len(self.counts)) * 1e-6
-
-    def p50(self) -> float:
-        return self.quantile(0.50)
-
-    def p99(self) -> float:
-        return self.quantile(0.99)
-
-    def mean(self) -> float:
-        return self.total_s / self.count if self.count else 0.0
 
 
 class AdmissionController:

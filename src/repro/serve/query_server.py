@@ -67,18 +67,19 @@ Two driving styles:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from concurrent.futures import (Future, InvalidStateError,
                                 ThreadPoolExecutor, wait)
 from typing import Callable, Optional
 
+from repro import obs
 from repro.core import ir, tiering
 from repro.core.passes.pipeline import Settings, preset
 from repro.core.plan_cache import PlanCache
 from repro.serve.admission import (AdmissionController, DeadlineExceeded,
-                                   LatencyHistogram, Overloaded, RateEMA,
-                                   TransientError)
+                                   Overloaded, RateEMA, TransientError)
 
 _UNSET = object()
 
@@ -110,9 +111,10 @@ class ServerStats:
     # shrinks from sustained underuse — see CacheStats)
     replans: int = 0
     shrinks: int = 0
-    # completion latency (submit -> result) of successful requests
-    latency: LatencyHistogram = dataclasses.field(
-        default_factory=LatencyHistogram)
+    # seconds the requests of executed groups waited between submit and
+    # the start of their group's execution: the coalescing window, plus
+    # any queueing for a pool thread
+    window_wait_s: float = 0.0
 
     def outstanding(self) -> int:
         """Requests admitted but not yet resolved.  Zero once the server
@@ -129,7 +131,8 @@ class _Entry:
     fut: Future
     deadline: Optional[float]        # monotonic; None = no deadline
     tenant: Optional[str]
-    t_submit: float                  # monotonic submit time (latency)
+    t_submit: float                  # monotonic submit time
+    req: int                         # the request's id in its spans
 
 
 @dataclasses.dataclass
@@ -194,6 +197,8 @@ class QueryServer:
         if warm_state_path is not None:
             self.cache.load(warm_state_path)
         self._arrivals = RateEMA()
+        self._req_ids = itertools.count(1)     # `req` of a request's spans
+        self._group_ids = itertools.count(1)   # `group` of server.group
         self._pool = ThreadPoolExecutor(max_workers=max_workers,
                                         thread_name_prefix="query-server")
         self._lock = threading.Lock()
@@ -211,62 +216,70 @@ class QueryServer:
     def submit(self, plan: ir.Plan, bindings: Optional[dict] = None,
                mode: str = "residual", *, tenant: Optional[str] = None,
                priority: int = 0, timeout_s=_UNSET) -> Future:
-        if self._closed:
-            raise RuntimeError("server is closed")
-        now = time.monotonic()
-        timeout = self.default_timeout_s if timeout_s is _UNSET else timeout_s
-        deadline = None if timeout is None else now + timeout
-        # degradation rung from the load *before* this request admits —
-        # it decides the settings, which decide the plan key, so it must
-        # be read before _prepare (a concurrent submit may shift the load
-        # by one; the rungs are heuristics, not invariants).
-        level = self._level()
-        settings = self._degraded_settings if level >= 2 else self.settings
-        # one canonicalization per request: compile-time params are baked
-        # into the plan here, so the key both dedups compilation and
-        # partitions the coalescing windows by plan structure.  Binding
-        # errors (missing params) raise here, before any accounting.
-        key, prepared, runtime, owned = self.cache._prepare(
-            plan, settings, bindings, mode)
-        fut: Future = Future()
-        entry = _Entry(runtime, fut, deadline, tenant, now)
-        full = None
-        with self._cv:
-            if self._closed:   # re-check under the lock: close() races us
+        req = next(self._req_ids)
+        with obs.span("server.submit", req=req):
+            if self._closed:
                 raise RuntimeError("server is closed")
-            self.stats.submitted += 1
-            self._arrivals.observe(now)
-            try:
-                self.admission.admit(tenant, priority)
-            except Overloaded:
-                self.stats.rejected += 1
-                raise
+            now = time.monotonic()
+            timeout = self.default_timeout_s if timeout_s is _UNSET \
+                else timeout_s
+            deadline = None if timeout is None else now + timeout
+            # degradation rung from the load *before* this request admits
+            # — it decides the settings, which decide the plan key, so it
+            # must be read before _prepare (a concurrent submit may shift
+            # the load by one; the rungs are heuristics, not invariants).
+            level = self._level()
+            settings = self._degraded_settings if level >= 2 \
+                else self.settings
+            # one canonicalization per request: compile-time params are
+            # baked into the plan here, so the key both dedups compilation
+            # and partitions the coalescing windows by plan structure.
+            # Binding errors (missing params) raise here, before any
+            # accounting.
+            key, prepared, runtime, owned = self.cache._prepare(
+                plan, settings, bindings, mode)
+            fut: Future = Future()
+            entry = _Entry(runtime, fut, deadline, tenant, now, req)
+            full = None
+            with self._cv:
+                if self._closed:   # re-check under the lock: close()
+                    #                    races us
+                    raise RuntimeError("server is closed")
+                self.stats.submitted += 1
+                self._arrivals.observe(now)
+                try:
+                    self.admission.admit(tenant, priority)
+                except Overloaded:
+                    self.stats.rejected += 1
+                    raise
+                if level >= 2:
+                    self.stats.shed_plan += 1
+                elif level >= 1:
+                    self.stats.shed_batch += 1
+                # completed futures (and their pinned results) don't
+                # accumulate
+                self._futures = [f for f in self._futures if not f.done()]
+                self._futures.append(fut)
+                w = self._windows.get(key)
+                if w is None:
+                    w = _Window(prepared, owned,
+                                now + self._window_len(level), settings,
+                                self._batch_cap(level))
+                    self._windows[key] = w
+                w.entries.append(entry)
+                if len(w.entries) >= w.max_batch:
+                    full = self._windows.pop(key)
+                else:
+                    self._cv.notify()
+            # the admission slot frees on ANY resolution (result, error,
+            # cancel, close): every resolution path runs the callbacks
+            fut.add_done_callback(
+                lambda f: self.admission.release(tenant))
             if level >= 2:
-                self.stats.shed_plan += 1
-            elif level >= 1:
-                self.stats.shed_batch += 1
-            # completed futures (and their pinned results) don't accumulate
-            self._futures = [f for f in self._futures if not f.done()]
-            self._futures.append(fut)
-            w = self._windows.get(key)
-            if w is None:
-                w = _Window(prepared, owned, now + self._window_len(level),
-                            settings, self._batch_cap(level))
-                self._windows[key] = w
-            w.entries.append(entry)
-            if len(w.entries) >= w.max_batch:
-                full = self._windows.pop(key)
-            else:
-                self._cv.notify()
-        # the admission slot frees on ANY resolution (result, error,
-        # cancel, close); successful completions also feed the latency
-        # histogram here, since every resolution path runs the callbacks
-        fut.add_done_callback(self._release_cb(tenant, now))
-        if level >= 2:
-            self.cache.note_degraded()
-        if full is not None:
-            self._dispatch(key, full)
-        return fut
+                self.cache.note_degraded()
+            if full is not None:
+                self._dispatch(key, full)
+            return fut
 
     def serve_batch(self, requests) -> list:
         """Submit (plan, bindings) pairs together, flush, drain in order."""
@@ -422,15 +435,6 @@ class QueryServer:
     def _batch_cap(self, level: int) -> int:
         return self.max_batch if level < 1 else max(1, self.max_batch // 4)
 
-    def _release_cb(self, tenant: Optional[str], t_submit: float):
-        def _done(f: Future) -> None:
-            self.admission.release(tenant)
-            if not f.cancelled() and f.exception() is None:
-                dt = time.monotonic() - t_submit
-                with self._lock:
-                    self.stats.latency.observe(dt)
-        return _done
-
     # -- coalescing tick ------------------------------------------------------
     def _flush_loop(self):
         """Flusher thread: dispatch each window when its deadline passes
@@ -448,8 +452,11 @@ class QueryServer:
                 if not popped:
                     nxt = min((w.deadline for w in self._windows.values()),
                               default=None)
-                    self._cv.wait(None if nxt is None
-                                  else max(0.0, nxt - now))
+                    if nxt is None:
+                        self._cv.wait()
+                    else:
+                        with obs.span("server.tick_wait"):
+                            self._cv.wait(max(0.0, nxt - now))
                     continue
             for key, w in popped:
                 self._dispatch(key, w)
@@ -561,69 +568,80 @@ class QueryServer:
                     window.settings)
 
     def _run_group(self, key, window: _Window):
+        t_start = time.monotonic()
         entries = self._expire(window.entries)
         if not entries:
             return
-        attempt = 0
-        while True:
-            try:
-                if self.tiered:
-                    # never block a request on XLA compilation: serve the
-                    # best READY tier now, promotion happens off-thread
-                    # (retries naturally pick up a freshly promoted tier)
-                    cq = self.cache._get_tiered_prepared(
-                        key, window.plan, entries[0].runtime, window.owned,
-                        window.settings, compile_hook=self.compile_hook)[0]
+        with obs.span("server.group", group=next(self._group_ids),
+                      reqs=" ".join(str(e.req) for e in entries)):
+            attempt = 0
+            while True:
+                try:
+                    with obs.span("cache.resolve"):
+                        if self.tiered:
+                            # never block a request on XLA compilation:
+                            # serve the best READY tier now, promotion
+                            # happens off-thread (retries naturally pick up
+                            # a freshly promoted tier)
+                            cq = self.cache._get_tiered_prepared(
+                                key, window.plan, entries[0].runtime,
+                                window.owned, window.settings,
+                                compile_hook=self.compile_hook)[0]
+                            with self._lock:
+                                self.stats.tier_served[cq.tier_name] = \
+                                    self.stats.tier_served.get(
+                                        cq.tier_name, 0) + 1
+                        else:
+                            cq = self._resolve_compiled(key, window,
+                                                        entries[0].runtime)
+                    if self.exec_hook is not None:
+                        self.exec_hook(key, attempt)
+                    runtimes = [e.runtime for e in entries]
+                    if len(runtimes) == 1:
+                        results = [cq.run(runtimes[0])]
+                        self.cache._note_compaction(cq, 1)
+                    else:
+                        # one vmapped XLA dispatch for the whole group
+                        results = self.cache.run_many(cq, runtimes)
+                    break
+                except BaseException as e:
+                    if attempt < self.max_retries \
+                            and isinstance(e, TransientError):
+                        # bounded restore-and-replay (fault_tolerance.py's
+                        # idiom): the window's request list is the
+                        # checkpoint — execution never mutates it — so the
+                        # replay is the same group minus anything whose
+                        # deadline passed while we backed off.
+                        with self._lock:
+                            self.stats.retries += 1
+                        time.sleep(self.retry_backoff_s * (2 ** attempt))
+                        attempt += 1
+                        entries = self._expire(entries)
+                        if not entries:
+                            return
+                        continue
+                    n = self._settle_entries(entries, e)
                     with self._lock:
-                        self.stats.tier_served[cq.tier_name] = \
-                            self.stats.tier_served.get(cq.tier_name, 0) + 1
-                else:
-                    cq = self._resolve_compiled(key, window,
-                                                entries[0].runtime)
-                if self.exec_hook is not None:
-                    self.exec_hook(key, attempt)
-                runtimes = [e.runtime for e in entries]
-                if len(runtimes) == 1:
-                    results = [cq.run(runtimes[0])]
-                    self.cache._note_compaction(cq, 1)
-                else:
-                    # one vmapped XLA dispatch for the whole group
-                    results = self.cache.run_many(cq, runtimes)
-                break
-            except BaseException as e:
-                if attempt < self.max_retries \
-                        and isinstance(e, TransientError):
-                    # bounded restore-and-replay (fault_tolerance.py's
-                    # idiom): the window's request list is the checkpoint
-                    # — execution never mutates it — so the replay is the
-                    # same group minus anything whose deadline passed
-                    # while we backed off.
-                    with self._lock:
-                        self.stats.retries += 1
-                    time.sleep(self.retry_backoff_s * (2 ** attempt))
-                    attempt += 1
-                    entries = self._expire(entries)
-                    if not entries:
-                        return
-                    continue
-                n = self._settle_entries(entries, e)
+                        self.stats.errors += n
+                    return
+            with obs.span("server.settle"):
+                delivered = cancelled = 0
+                for e, res in zip(entries, results):
+                    # a client may have cancelled its future while the
+                    # window was pending; that must not poison the rest of
+                    # the group
+                    st = self._settle(e.fut, result=res)
+                    if st == "done":
+                        delivered += 1
+                    elif st == "cancelled":
+                        cancelled += 1
                 with self._lock:
-                    self.stats.errors += n
-                return
-        delivered = cancelled = 0
-        for e, res in zip(entries, results):
-            # a client may have cancelled its future while the window
-            # was pending; that must not poison the rest of the group
-            st = self._settle(e.fut, result=res)
-            if st == "done":
-                delivered += 1
-            elif st == "cancelled":
-                cancelled += 1
-        with self._lock:
-            self.stats.completed += delivered
-            self.stats.cancelled += cancelled
-            self.stats.batches += 1
-            if len(results) > 1:
-                self.stats.coalesced += len(results)
-            self.stats.replans = self.cache.stats.replans
-            self.stats.shrinks = self.cache.stats.shrinks
+                    self.stats.completed += delivered
+                    self.stats.cancelled += cancelled
+                    self.stats.batches += 1
+                    if len(results) > 1:
+                        self.stats.coalesced += len(results)
+                    self.stats.window_wait_s += sum(
+                        t_start - e.t_submit for e in entries)
+                    self.stats.replans = self.cache.stats.replans
+                    self.stats.shrinks = self.cache.stats.shrinks

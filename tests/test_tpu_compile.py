@@ -9,6 +9,7 @@ stays off around these compiles (their entries cannot be read back
 without a chip)."""
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,5 +119,10 @@ def test_opt_pallas_q6_compiles_for_v5e(db, one_chip):
     shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
               for k, v in cq.bind().items()}
     compiled = jax.jit(cq.fn).lower(shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel keeps its `name=` in the chip's program, on the custom
+    # call the trace's kernel events are found by
+    assert re.search(r'%pipeline[.\d]* = .*custom_call_target="tpu_custom_call"',
+                     text)
     assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
