@@ -13,6 +13,10 @@ This is the LegoBase code generator, reorganized into explicit layers:
   * the *runtime layer* (`repro.core.plan_cache`, `repro.serve`) reuses
     CompiledQuery across executions.
 
+Every input but the `param/<name>` scalars lives on the device once per
+database (`Database.device_input`), shared by every compiled entry that
+reads it, so a dispatch copies only the parameters host→device.
+
 Query-specific literals (date-slice bounds, dictionary codes, key domains,
 strides, pruned column sets) are baked in at staging time exactly as the
 paper's generated C bakes them in.  `Param` nodes are the exception: a
@@ -175,8 +179,8 @@ class CompiledQuery:
         # sharded execution: the Sharding pass resolved the same settings,
         # so the mesh shape here matches the per-shard capacities it
         # planted.  The staged fn is shard_map-wrapped below; partitioned
-        # inputs are device_put with a NamedSharding after the collection
-        # walk so jit consumes them without host-side resharding.
+        # inputs are placed with a NamedSharding after the collection walk
+        # so jit consumes them without host-side resharding.
         self.n_shards = resolve_shards(settings)
         self._mesh = data_mesh(self.n_shards) if self.n_shards > 1 else None
 
@@ -196,7 +200,7 @@ class CompiledQuery:
         # 1. collection walk (numpy, 8-row samples): registers inputs and
         #    output schema; every static decision is exercised here.
         t0 = time.perf_counter()
-        self.inputs: dict[str, np.ndarray] = {}
+        self.inputs: dict = {}
 
         def collect_input(key, make):
             if key not in self.inputs:
@@ -219,12 +223,20 @@ class CompiledQuery:
         # register every declared param unconditionally.
         for name, dtype in self.param_spec.items():
             sampler.param(Param(name, dtype))
+        # every input but the `param/<name>` scalars is the database's
+        # device copy, shared by every entry that reads it.  Under the
+        # mesh the unpartitioned ones are replicated on it: a
+        # single-device array would be refused by the shard_map program.
+        part = rep = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            ns = NamedSharding(self._mesh, PartitionSpec(AXIS))
-            for k in self.sharded_keys:
-                self.inputs[k] = jax.device_put(self.inputs[k], ns)
+            part = NamedSharding(self._mesh, PartitionSpec(AXIS))
+            rep = NamedSharding(self._mesh, PartitionSpec())
+        for k, v in self.inputs.items():
+            if not k.startswith("param/"):
+                self.inputs[k] = db.device_input(
+                    k, v, part if k in self.sharded_keys else rep)
 
         # 2. the staged program.  `body` is the staged walk shared by the
         #    scalar and the batched entry point; the entry points differ
@@ -320,9 +332,10 @@ class CompiledQuery:
         return compiled
 
     # -- parameter re-binding --------------------------------------------------
-    def bind(self, params: Optional[dict] = None) -> dict[str, np.ndarray]:
+    def bind(self, params: Optional[dict] = None) -> dict:
         """Input dict for one execution: base columns + index structures
-        (shared across bindings) and the per-execution parameter scalars.
+        (device arrays shared across bindings and entries) and the
+        per-execution parameter scalars (host numpy).
 
         `params=None` executes under the construction-time bindings; a
         non-None dict must name *every* runtime parameter — a partial dict
@@ -348,7 +361,7 @@ class CompiledQuery:
                            f"{missing}")
         return params
 
-    def bind_many(self, bindings_list) -> dict[str, np.ndarray]:
+    def bind_many(self, bindings_list) -> dict:
         """Input dict for one *batched* execution: base columns unchanged,
         `param/<name>` stacked to a (bucket,) leading-axis vector — the
         batch padded to `bucket_size(B)` by repeating the last binding
@@ -599,7 +612,7 @@ class CompiledQueryBatch:
                 "CompiledQueryBatch does not compose with sharded "
                 "execution (Settings.shards != 1)")
         self.queries = [CompiledQuery(p, db, settings) for p in plans]
-        self.inputs: dict[str, np.ndarray] = {}
+        self.inputs: dict = {}
         for q in self.queries:
             self.inputs.update(q.inputs)
         fns = [q.fn for q in self.queries]

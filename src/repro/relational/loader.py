@@ -24,10 +24,16 @@ In the JAX adaptation the structures are:
 
 All structures are built lazily and cached; `aux_nbytes()` reports their
 memory for the Fig-20 experiment.
+
+The inputs of compiled programs live on the device, one copy per database
+(`device_input`): every compiled entry that reads a column, permutation or
+dictionary shares that copy, and `reload()` drops them all.
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
+import threading
 import time
 from typing import Optional
 
@@ -45,6 +51,13 @@ from repro.relational.tpch import generate
 _FINGERPRINTS = itertools.count()
 
 
+@dataclasses.dataclass
+class DeviceStats:
+    uploads: int = 0          # device_put calls the store made
+    hits: int = 0             # lookups served by an array already placed
+    resident_bytes: int = 0   # bytes the store holds, summed over devices
+
+
 class Database:
     def __init__(self, tables: dict[str, Table]):
         self.fingerprint: int = next(_FINGERPRINTS)
@@ -53,7 +66,12 @@ class Database:
         self._fk_csr: dict[tuple[str, str], tuple[np.ndarray, np.ndarray]] = {}
         self._date_cluster: dict[tuple[str, str], tuple[np.ndarray, np.ndarray]] = {}
         self._slice_bounds: dict[tuple, tuple[int, int]] = {}
+        # (input key, sharding) -> jax.Array: the device-resident input
+        # store.  QueryServer's pool threads and the tiering compile thread
+        # fill it at once, so every access takes the lock.
         self._device_cols: dict[tuple, object] = {}
+        self._device_lock = threading.Lock()
+        self._device_stats = DeviceStats()
         self._shard_plans: dict[int, "ShardPlan"] = {}
 
     # -- construction -------------------------------------------------------
@@ -76,7 +94,9 @@ class Database:
         self.fingerprint = next(_FINGERPRINTS)
         self._content_fp = None
         self.tables = tables
-        self._device_cols.clear()
+        with self._device_lock:
+            self._device_cols.clear()
+            self._device_stats.resident_bytes = 0
         self.reset_aux()
 
     def content_fingerprint(self) -> str:
@@ -107,6 +127,32 @@ class Database:
                         h.update(np.ascontiguousarray(arr[::step]).tobytes())
             self._content_fp = h.hexdigest()[:16]
         return self._content_fp
+
+    # -- device-resident inputs -------------------------------------------------
+    def device_input(self, key: str, host: np.ndarray, sharding=None):
+        """The device copy of the compiled-program input `key`, placed with
+        `sharding` (None: the default device) on first use and shared by
+        every later caller.  An input key names its content within one
+        database (`scan.py`, `join.py`: table, kind, column, shard count),
+        so the key and the placement are the whole identity."""
+        import jax
+
+        k = (key, sharding)
+        with self._device_lock:
+            arr = self._device_cols.get(k)
+            if arr is None:
+                arr = self._device_cols[k] = jax.device_put(host, sharding)
+                self._device_stats.uploads += 1
+                self._device_stats.resident_bytes += sum(
+                    s.data.nbytes for s in arr.addressable_shards)
+            else:
+                self._device_stats.hits += 1
+            return arr
+
+    def device_stats(self) -> DeviceStats:
+        """A snapshot of the input store's counters."""
+        with self._device_lock:
+            return dataclasses.replace(self._device_stats)
 
     # -- physical co-partitioning (§3.2.1 over a device mesh) ----------------
     def shard_plan(self, n: int) -> "ShardPlan":
