@@ -161,28 +161,24 @@ class JaxBackend:
 
     # -- mesh collectives (only traced inside shard_map: `axis` must be a
     # -- bound mesh axis name, which compile.py guarantees by setting
-    # -- StageCtx.axis iff the staged fn is shard_map-wrapped)
-    def psum(self, x, axis):
-        import jax
+    # -- StageCtx.axis iff the staged fn is shard_map-wrapped).  The
+    # -- combines stage under `op.combine`, so the device trace tells their
+    # -- time from the operator's own work.
+    def _combine(self, op, x, axis):
+        with self._jax.named_scope("op.combine"):
+            return op(x, axis)
 
-        return jax.lax.psum(x, axis)
+    def psum(self, x, axis):
+        return self._combine(self._jax.lax.psum, x, axis)
 
     def pmax(self, x, axis):
-        import jax
-
-        return jax.lax.pmax(x, axis)
+        return self._combine(self._jax.lax.pmax, x, axis)
 
     def pmin(self, x, axis):
-        import jax
-
-        return jax.lax.pmin(x, axis)
+        return self._combine(self._jax.lax.pmin, x, axis)
 
     def all_gather(self, x, axis, tiled=False):
-        import jax
-
-        return jax.lax.all_gather(x, axis, tiled=tiled)
+        return self._jax.lax.all_gather(x, axis, tiled=tiled)
 
     def axis_index(self, axis):
-        import jax
-
-        return jax.lax.axis_index(axis)
+        return self._jax.lax.axis_index(axis)

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import functools
 import threading
 import time
 from typing import Optional
@@ -77,6 +78,27 @@ _SAMPLE = 8
 # on pool threads, so the increment takes a lock.)
 STAGINGS = 0
 _STAGINGS_LOCK = threading.Lock()
+
+
+# the collectives a sharded program stages: Exchange gathers, in-operator
+# combines and the count gather (`backend.JaxBackend`)
+_COLLECTIVES = ("all_gather", "psum", "pmax", "pmin")
+
+
+def _collective_bytes(jaxpr) -> int:
+    """Bytes of every collective's result in a jaxpr and its sub-jaxprs:
+    what each shard holds afterwards."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in _COLLECTIVES:
+            total += sum(v.aval.size * v.aval.dtype.itemsize
+                         for v in eqn.outvars)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    total += _collective_bytes(sub)
+    return total
 
 
 def bucket_size(n: int) -> int:
@@ -316,6 +338,16 @@ class CompiledQuery:
             fn_many if self._mesh is None else shard_wrap(fn_many))
         self.stage_time = time.perf_counter() - t0
         self._compile_time: Optional[float] = None
+
+    @functools.cached_property
+    def exchange_bytes(self) -> int:
+        """Bytes each shard receives per dispatch of the scalar program,
+        from its staged shapes (a trace, shared with the dispatch's own):
+        the results of its Exchange gathers, combines and count gather.
+        0 unsharded, where nothing moves."""
+        if self._mesh is None:
+            return 0
+        return _collective_bytes(self._jitted.trace(self.bind()).jaxpr.jaxpr)
 
     # -- explicit compile (for the Fig-22 experiment) -------------------------
     def compile(self):

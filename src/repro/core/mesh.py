@@ -1,10 +1,10 @@
 """Small 1-D data mesh for sharded query execution.
 
-`launch/mesh.py` builds the production 2-D (data, model) meshes and
-insists on 256/512-device slices; query sharding needs the opposite — a
-tiny 1-D mesh over however many devices this host actually has (CPU CI
-simulates them with `XLA_FLAGS=--xla_force_host_platform_device_count=N`,
-which must be set before the first jax import — see tests/conftest.py).
+One axis, `data`, over the first n local devices: the partitioned tables
+(loader.ShardPlan) are split along it, everything else is replicated on
+it.  On the CPU the tests simulate devices with
+`XLA_FLAGS=--xla_force_host_platform_device_count=N`, which must be set
+before the first jax import (tests/conftest.py).
 
 `Settings.shards` semantics: 1 = single-device (no mesh, no shard_map),
 0 = auto (every local device), n>1 = exactly n devices (error when the

@@ -195,6 +195,10 @@ def preset(name: str) -> Settings:
         return Settings(use_pallas=True)
     if name == "opt-shard":      # beyond paper: + mesh-sharded execution
         return Settings(shards=0)
+    if name == "opt-shard4":     # the same over exactly four devices,
+        # keeping the planned compaction capacities: each shrink re-stages
+        # a program, which costs a four-chip deployment seconds per query
+        return Settings(shards=4, compact_feedback=False)
     if name == "mask-only":      # serving degradation rung: see degrade()
         return degrade(Settings())
     raise KeyError(name)

@@ -126,3 +126,16 @@ def test_operators_scope_their_hlo(db, query, scopes):
     text = jax.jit(cq.fn).lower(cq.bind()).as_text(debug_info=True)
     for scope in scopes:
         assert f"/{scope}" in text
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 devices")
+@pytest.mark.parametrize("query,scopes", [
+    ("q1", ("op.agg/op.combine",)),           # dense agg partials: psum
+    ("q4", ("op.join/op.combine",)),          # exists_flag union: pmax
+    ("q18", ("op.exchange",)),                # the gather below its agg
+])
+def test_cross_chip_ops_scope_their_hlo(db, query, scopes):
+    cq = CompiledQuery(QUERIES[query](), db, preset("opt-shard4"))
+    text = jax.jit(cq.fn).lower(cq.bind()).as_text(debug_info=True)
+    for scope in scopes:
+        assert f"/{scope}" in text

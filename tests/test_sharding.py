@@ -7,6 +7,8 @@ when that failed (jax was already loaded), the mesh tests skip rather
 than mis-measure against a 1-device "mesh"."""
 import copy
 import dataclasses
+import math
+import re
 
 import pytest
 
@@ -116,3 +118,77 @@ def test_four_shard_matches_oracle(db, oracle, qname):
     cq = CompiledQuery(QUERIES[qname](), db, sharded(4))
     assert cq.n_shards == 4
     assert_same(cq.run(), oracle[qname], qname in SORT_INSENSITIVE)
+
+
+# -- the four-chip preset (`opt-shard4`: chipbench's power-opt-4chip) --------
+
+POWER = ("q1", "q3", "q4", "q5", "q6", "q7", "q9full", "q10", "q12", "q13",
+         "q14", "q17", "q18", "q19")
+# a collective's result shape in the lowered HLO: `%x = s32[400]{0} all-gather(`
+_HLO_COLLECTIVE = re.compile(
+    r"= (.*?) (all-gather|all-reduce|reduce-scatter|all-to-all)\(")
+_HLO_SHAPE = re.compile(r"\b(pred|s32|u32|f32)\[([\d,]*)\]")
+_BYTES = {"pred": 1, "s32": 4, "u32": 4, "f32": 4}
+
+
+def _hlo_collective_bytes(text: str) -> int:
+    """Bytes of every collective's result in an HLO module's text."""
+    total = 0
+    for m in _HLO_COLLECTIVE.finditer(text):
+        for dtype, dims in _HLO_SHAPE.findall(m.group(1)):
+            total += _BYTES[dtype] * math.prod(
+                int(d) for d in dims.split(",") if d)
+    return total
+
+
+@_needs(4)
+def test_four_chip_preset_compiles_every_entry_at_four_shards(db):
+    cache = PlanCache(db)
+    for q in POWER:
+        cache.get(QUERIES[q](), preset("opt-shard4"))
+    entries = list(cache._entries.values())
+    assert len(entries) == len(POWER)
+    assert {cq.n_shards for cq in entries} == {4}
+
+
+@_needs(4)
+def test_four_chip_preset_keeps_its_planned_capacities(db, oracle):
+    # with feedback, q12's underused compaction points shrink after
+    # `compact_shrink_after` runs, and the shrink stages a second program
+    s = preset("opt-shard4")
+    for settings, shrinks in ((dataclasses.replace(s, compact_feedback=True),
+                               1), (s, 0)):
+        cache = PlanCache(db)
+        for _ in range(s.compact_shrink_after + 2):
+            got = cache.execute(QUERIES["q12"](), settings)
+        assert (cache.stats.shrinks, cache.stats.compiles) == \
+            (shrinks, 1 + shrinks)
+        assert_same(got, oracle["q12"], "q12" in SORT_INSENSITIVE)
+
+
+@_needs(4)
+def test_exchange_bytes_are_the_gathered_and_combined_shapes(db, oracle):
+    import jax
+
+    cq = CompiledQuery(QUERIES["q18"](), db, preset("opt-shard4"))
+    assert any(isinstance(n, ir.Exchange) for n in ir.walk(cq.plan))
+    assert_same(cq.run(), oracle["q18"], "q18" in SORT_INSENSITIVE)
+    got = cq.exchange_bytes
+    assert cq.n_traces == 1                  # read from the dispatch's trace
+    hlo = jax.jit(cq.fn).lower(cq.bind()).as_text(dialect="hlo")
+    assert got == _hlo_collective_bytes(hlo) > 0
+
+    single = CompiledQuery(QUERIES["q18"](), db, preset("opt"))
+    single.run()
+    assert single.exchange_bytes == 0
+
+
+def test_four_chip_preset_refuses_fewer_devices(db, monkeypatch):
+    import jax
+
+    few = jax.devices()[:3]
+    monkeypatch.setattr(jax, "devices", lambda *a: few)
+    with pytest.raises(ValueError, match="shards=4"):
+        resolve_shards(preset("opt-shard4"))
+    with pytest.raises(ValueError, match="shards=4"):
+        PlanCache(db).get(QUERIES["q6"](), preset("opt-shard4"))
