@@ -51,9 +51,11 @@ repeated overflows and shrinks them after sustained underuse.
 """
 from __future__ import annotations
 
+import collections
 import copy
 import dataclasses
 import functools
+import re
 import threading
 import time
 from typing import Optional
@@ -62,7 +64,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import ir
-from repro.core.backend import JaxBackend, NumpyBackend
+from repro.core.backend import RANK_OP, JaxBackend, NumpyBackend
 from repro.core.expr import Param
 from repro.core.mesh import AXIS, data_mesh, resolve_shards
 from repro.core.operators import StageCtx, frame_nrows
@@ -83,6 +85,7 @@ _STAGINGS_LOCK = threading.Lock()
 # the collectives a sharded program stages: Exchange gathers, in-operator
 # combines and the count gather (`backend.JaxBackend`)
 _COLLECTIVES = ("all_gather", "psum", "pmax", "pmin")
+_RANK_SCOPE = re.compile(r"\brank\.(\w+)")
 
 
 def _collective_bytes(jaxpr) -> int:
@@ -99,6 +102,25 @@ def _collective_bytes(jaxpr) -> int:
                 if hasattr(sub, "eqns"):
                     total += _collective_bytes(sub)
     return total
+
+
+def _rank_sites(jaxpr, route=None, sites=None) -> collections.Counter:
+    """Rank sites in a jaxpr and its sub-jaxprs, by route: the equations
+    that stage each site's rank (`backend.RANK_OP`) inside a
+    `rank.<route>` scope.  A sub-jaxpr's name stacks are its own, so the
+    route found on an equation holds for everything inside it."""
+    sites = collections.Counter() if sites is None else sites
+    for eqn in jaxpr.eqns:
+        m = _RANK_SCOPE.search(str(eqn.source_info.name_stack))
+        r = m.group(1) if m else route
+        if r is not None and eqn.primitive.name == RANK_OP.get(r):
+            sites[r] += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _rank_sites(sub, r, sites)
+    return sites
 
 
 def bucket_size(n: int) -> int:
@@ -348,6 +370,14 @@ class CompiledQuery:
         if self._mesh is None:
             return 0
         return _collective_bytes(self._jitted.trace(self.bind()).jaxpr.jaxpr)
+
+    @functools.cached_property
+    def rank_routes(self) -> dict:
+        """Rank sites of the scalar program by route (`JaxBackend.compact`
+        and `.searchsorted`, each staged under `rank.<route>`), e.g.
+        {'scatter': 1, 'search': 2}: from its staged jaxpr (a trace,
+        shared with the dispatch's own)."""
+        return dict(_rank_sites(self._jitted.trace(self.bind()).jaxpr.jaxpr))
 
     # -- explicit compile (for the Fig-22 experiment) -------------------------
     def compile(self):

@@ -5,10 +5,15 @@ downstream of a selective predicate: a 0.2%-selectivity query still
 gathers, sorts and segment-reduces over every row.  `Compact` converts the
 frame to the dense, layout-specialized representation the paper's §3.2
 argues for: `backend.compact(mask, capacity)` ranks the valid rows with a
-cumsum and scatters their ids into an index vector of *statically planned*
-`capacity` (JAX shapes must be static), then every column is gathered down
-to `capacity` rows.  Downstream operators are oblivious — they see an
-ordinary, much smaller Frame whose mask marks only the pad slots.
+cumsum and finds the row id of each of the *statically planned*
+`capacity` output slots (JAX shapes must be static), then every column is
+gathered down to `capacity` rows.  How the ids are found is the backend's
+rank route, picked from the static shapes (`backend.compact_route`): a
+binary search over the running count where the capacity is small against
+the frame, a scatter of every valid row's id to its rank where the
+search's dependent gathers would cost more.  Both give the same ids.
+Downstream operators are oblivious — they see an ordinary, much smaller
+Frame whose mask marks only the pad slots.
 
 If more rows survive than the planner estimated, the surplus is dropped
 from the index vector; the point's TRUE valid count is registered through
@@ -18,9 +23,9 @@ its planned capacity: on overflow it re-executes the uncompacted fallback
 plan (an estimate can only ever cost time), and either way the measured
 counts feed the plan cache's adaptive capacity feedback.
 
-Under `Settings.use_pallas` the XLA three-op sequence (cumsum →
-searchsorted → gather-rank) is replaced by the single-HBM-pass Pallas
-kernel (`repro.kernels.pipeline`), and when the child is a Select whose
+Under `Settings.use_pallas` the XLA sequence (cumsum → rank → gather)
+is replaced by the single-HBM-pass Pallas kernel
+(`repro.kernels.pipeline`), and when the child is a Select whose
 predicate is kernel-safe over an elementwise chain, predicate evaluation
 itself is fused into the same pass (`compact_pred`): the mask is never
 materialized in HBM.  `translate` points additionally emit the CSR
