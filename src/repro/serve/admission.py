@@ -12,11 +12,9 @@ The overload-hardening layer of `QueryServer` (docs/architecture.md §10):
   * typed errors — `Overloaded` (admission rejection), `DeadlineExceeded`
     (a request's deadline passed before its group executed), and
     `TransientError` (the retryable fault class: the server's bounded
-    retry-with-backoff only replays a group whose failure is transient,
-    mirroring `runtime/fault_tolerance.py`'s restore-and-replay idiom).
-  * `RateEMA` — exponentially weighted arrival-interval tracker (the
-    `StragglerStats` idiom pointed at arrivals instead of step times);
-    drives the adaptive coalescing window.
+    retry-with-backoff only replays a group whose failure is transient).
+  * `RateEMA` — exponentially weighted arrival-interval tracker; drives
+    the adaptive coalescing window.
 """
 from __future__ import annotations
 
@@ -42,17 +40,16 @@ class DeadlineExceeded(RuntimeError):
 
 
 class TransientError(RuntimeError):
-    """A fault the server may retry: the failed group is replayed once
-    (with backoff) against the same compiled entry — restore-and-replay,
-    like `TrainDriver`'s checkpoint recovery, but the 'checkpoint' is the
-    window's request list, which execution never mutates."""
+    """A fault the server may retry: the failed group is replayed (with
+    backoff) against the same compiled entry.  Execution never mutates the
+    window's request list, so the replay runs it as it was."""
 
 
 @dataclasses.dataclass
 class RateEMA:
-    """EMA of inter-arrival times (`StragglerStats.observe` pointed at
-    arrivals): `interval()` is the smoothed gap between requests, from
-    which the server derives its coalescing-window length."""
+    """EMA of inter-arrival times: `interval()` is the smoothed gap
+    between requests, from which the server derives its coalescing-window
+    length."""
     alpha: float = 0.1
     ema: float = 0.0
     count: int = 0

@@ -1,24 +1,23 @@
 """Concurrent parameterized query server over the plan cache.
 
-The analytics twin of `batcher.py`'s serving engine: requests arrive
-concurrently, each naming a plan + parameter bindings; execution goes
-through a shared `PlanCache` so only the first request for a plan shape
-pays staging + XLA JIT, and *in-flight* compilations are deduplicated — a
-request arriving while another request is already compiling the same key
-parks on that compilation instead of starting a second one.
+Requests arrive concurrently, each naming a plan + parameter bindings;
+execution goes through a shared `PlanCache` so only the first request
+for a plan shape pays staging + XLA JIT, and *in-flight* compilations
+are deduplicated — a request arriving while another request is already
+compiling the same key parks on that compilation instead of starting a
+second one.
 
-Execution is *coalesced*, mirroring `batcher.py`'s tick discipline:
-requests arriving within one window that share a plan key are grouped
-into a single batch, executed as ONE vmapped XLA dispatch
-(`CompiledQuery.run_many` via `PlanCache.run_many`), and their results
-scattered back to the per-request futures.  A window flushes when it
-fills (`max_batch`), when its deadline expires (the flusher thread's
-tick), or when `flush()`/`drain()` forces it — `drain` flushes partial
-windows, so no request can hang because traffic stopped mid-tick.  The
-window length adapts to the observed arrival rate (an EMA of
-inter-arrival gaps, the `StragglerStats` idiom): sparse traffic widens
-the window to coalesce more, dense traffic narrows it toward the time a
-full batch takes to arrive.
+Execution is *coalesced*: requests arriving within one window that share
+a plan key are grouped into a single batch, executed as ONE vmapped XLA
+dispatch (`CompiledQuery.run_many` via `PlanCache.run_many`), and their
+results scattered back to the per-request futures.  A window flushes
+when it fills (`max_batch`), when its deadline expires (the flusher
+thread's tick), or when `flush()`/`drain()` forces it — `drain` flushes
+partial windows, so no request can hang because traffic stopped
+mid-tick.  The window length adapts to the observed arrival rate
+(`RateEMA`, an EMA of inter-arrival gaps): sparse traffic widens the
+window to coalesce more, dense traffic narrows it toward the time a full
+batch takes to arrive.
 
 Overload hardening (docs/architecture.md §10):
 
@@ -32,9 +31,8 @@ Overload hardening (docs/architecture.md §10):
     the rest of the group;
   * bounded retry — a group whose execution raises a `TransientError`
     is replayed up to `max_retries` times with exponential backoff
-    against the same compiled entry (restore-and-replay, mirroring
-    `runtime/fault_tolerance.py`; the window's request list is the
-    checkpoint and execution never mutates it);
+    against the same compiled entry; execution never mutates the
+    window's request list, so a retry replays it as it was;
   * a degradation ladder keyed off the admission load, expressed as
     *tier demotion* over the same `core.tiering.TierLadder` the plan
     cache promotes along (docs §11): first shed to smaller coalescing
@@ -607,11 +605,10 @@ class QueryServer:
                 except BaseException as e:
                     if attempt < self.max_retries \
                             and isinstance(e, TransientError):
-                        # bounded restore-and-replay (fault_tolerance.py's
-                        # idiom): the window's request list is the
-                        # checkpoint — execution never mutates it — so the
-                        # replay is the same group minus anything whose
-                        # deadline passed while we backed off.
+                        # bounded replay: execution never mutates the
+                        # window's request list, so the replay is the same
+                        # group minus anything whose deadline passed while
+                        # we backed off.
                         with self._lock:
                             self.stats.retries += 1
                         time.sleep(self.retry_backoff_s * (2 ** attempt))

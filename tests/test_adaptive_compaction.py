@@ -231,6 +231,43 @@ def test_feedback_off_never_replans(db):
     assert cq1 is cq0
 
 
+# initial selective binding overlay per template, applied over the
+# template's defaults: the initial binding undershoots the steady (default)
+# one, so the feedback loop has something to correct.
+SCHEDULES = {
+    "q3": {"cutoff": days("1998-11-01")},
+    "q6": {"qty_max": 2.0},
+    "q12": {"receipt_lo": days("1994-01-01"),
+            "receipt_hi": days("1994-02-01")},
+    "q14": {"ship_lo": days("1995-09-01"),
+            "ship_hi": days("1995-09-08")},
+}
+STEADY_RUNS = 8
+
+
+@pytest.mark.parametrize("qname", sorted(SCHEDULES))
+def test_binding_shift_converges_and_matches_oracle(db, qname):
+    """A plan compiled for a selective binding, then driven by the steady
+    binding under the default feedback settings: every answer equals the
+    oracle's, and by the last steady run the entry no longer overflows
+    (q6/q14 end in fusing scalar aggregations and plant no points)."""
+    build, defaults = PARAM_QUERIES[qname]
+    initial = dict(defaults, **SCHEDULES[qname])
+    s = preset("opt")
+    cache = PlanCache(db)
+    oracle = VolcanoEngine(db)
+
+    assert_same(cache.execute(build(), s, initial),
+                oracle.execute(build(), initial), sort_insensitive=True)
+    want = oracle.execute(build(), defaults)
+    for _ in range(STEADY_RUNS):
+        overflows = cache.stats.overflows
+        assert_same(cache.execute(build(), s, defaults), want,
+                    sort_insensitive=True)
+    assert cache.stats.overflows == overflows, \
+        f"{qname}: still overflowing after {STEADY_RUNS} steady bindings"
+
+
 # ---------------------------------------------------------------------------
 # batch padding is masked out of overflow accounting (satellite bugfix)
 # ---------------------------------------------------------------------------

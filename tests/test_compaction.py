@@ -25,6 +25,9 @@ CONFIG_PARAMS = [
     for c in CONFIGS
 ]
 TARGETS = ["q3", "q6", "q19"]
+# every query whose predicates leave a small fraction of a large frame
+# alive (the q6/q19 class)
+SELECTIVE = ["q3", "q5", "q6", "q7", "q10", "q12", "q17", "q19"]
 
 
 def _compacted(settings):
@@ -40,7 +43,7 @@ def _mask_only(settings):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("config", CONFIG_PARAMS)
-@pytest.mark.parametrize("qname", TARGETS)
+@pytest.mark.parametrize("qname", SELECTIVE)
 def test_compacted_matches_mask_only_and_oracle(db, qname, config):
     want = VolcanoEngine(db).execute(QUERIES[qname]())
     on = CompiledQuery(QUERIES[qname](), db,

@@ -25,24 +25,34 @@ def assert_matches(got, want):
 
 
 # ---------------------------------------------------------------------------
-# acceptance criterion: same parameterized query, two bindings, ONE staging,
-# both matching the Volcano oracle under every preset in CONFIGS.
+# acceptance criterion: same parameterized query, re-bound default ->
+# alternative -> default, ONE staging, every answer matching the Volcano
+# oracle: q6 under every preset in CONFIGS, every other template at `opt`.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("config", CONFIGS)
-def test_rebind_single_staging_matches_oracle(db, config):
-    build, defaults = PARAM_QUERIES["q6"]
-    alt = dict(defaults, **ALT_BINDINGS["q6"])
+REBIND_CASES = ([(config, "q6") for config in CONFIGS]
+                + [("opt", q) for q in sorted(PARAM_QUERIES) if q != "q6"])
+# templates whose `opt` plan plants compaction points at this scale: each
+# of their executions counts in `CacheStats.compactions`
+COMPACTING = {"q3", "q12"}
+
+
+@pytest.mark.parametrize("config,qname", REBIND_CASES)
+def test_rebind_single_staging_matches_oracle(db, config, qname):
+    build, defaults = PARAM_QUERIES[qname]
+    alt = dict(defaults, **ALT_BINDINGS[qname])
     cache = PlanCache(db)
     oracle = VolcanoEngine(db)
     before = compile_mod.STAGINGS
-    for bindings in (defaults, alt):
+    for bindings in (defaults, alt, defaults):
         got = cache.execute(build(), preset(config), bindings)
         want = oracle.execute(build(), bindings)
         assert_matches(got, want)
     assert compile_mod.STAGINGS - before == 1, \
         "re-binding must not re-stage/re-JIT"
-    assert cache.stats == type(cache.stats)(hits=1, misses=1, compiles=1)
+    assert cache.stats == type(cache.stats)(
+        hits=2, misses=1, compiles=1,
+        compactions=3 if qname in COMPACTING else 0)
     # and the jitted program itself traced exactly once
     (cq,) = [cache.get(build(), preset(config), defaults)[0]]
     assert cq.n_traces == 1

@@ -109,9 +109,8 @@ def test_batch_compile_rejects_mesh(db):
         CompiledQueryBatch([QUERIES["q6"]()], db, sharded(2))
 
 
-# -- full sweep: 4-device mesh (slow) ----------------------------------------
+# -- full sweep: 4-device mesh ------------------------------------------------
 
-@pytest.mark.slow
 @_needs(4)
 @pytest.mark.parametrize("qname", sorted(QUERIES))
 def test_four_shard_matches_oracle(db, oracle, qname):

@@ -33,6 +33,7 @@ from repro.core.volcano import OracleQuery, VolcanoEngine
 from repro.core.passes.pipeline import degrade, preset
 from repro.relational.queries import (PARAM_ALT_BINDINGS, PARAM_QUERIES,
                                       QUERIES)
+from repro.relational.schema import days
 from repro.serve.query_server import QueryServer
 from tests.test_queries import assert_same
 
@@ -274,6 +275,41 @@ def test_warm_state_round_trip(db, tmp_path):
     assert rec.replans == 2
     # live observations beat stale disk: loading twice doesn't clobber
     assert fresh.load(path) == 0
+
+
+# initial selective binding overlay per template: the default binding that
+# follows overflows the first plan's capacities, so the feedback loop
+# converges on overrides worth persisting
+WARM_SCHEDULES = {
+    "q3": {"cutoff": days("1998-11-01")},
+    "q12": {"receipt_lo": days("1994-01-01"),
+            "receipt_hi": days("1994-02-01")},
+}
+
+
+@pytest.mark.parametrize("qname", sorted(WARM_SCHEDULES))
+def test_warm_restart_plans_at_converged_capacities(db, tmp_path, qname):
+    """A converged cache saved and loaded into a fresh one: the fresh
+    cache's first compile uses the converged capacities and its first
+    request does not overflow."""
+    build, defaults = PARAM_QUERIES[qname]
+    path = str(tmp_path / "warm.json")
+    cache = PlanCache(db)
+    cache.execute(build(), OPT, dict(defaults, **WARM_SCHEDULES[qname]))
+    for _ in range(8):
+        cache.execute(build(), OPT, defaults)
+    converged, _ = cache.get(build(), OPT, defaults)
+    assert converged.compaction_points
+    assert cache.save(path) >= 1
+
+    fresh = PlanCache(db)
+    assert fresh.load(path) >= 1
+    assert_same(fresh.execute(build(), OPT, defaults),
+                VolcanoEngine(db).execute(build(), defaults),
+                sort_insensitive=True)
+    cq, _ = fresh.get(build(), OPT, defaults)
+    assert list(cq.capacities) == list(converged.capacities)
+    assert cq.n_overflows == 0
 
 
 def test_corrupt_or_mismatched_warm_state_is_cold_start(db, tmp_path):
